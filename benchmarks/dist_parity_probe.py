@@ -6,7 +6,10 @@ lives, invoked as `python -m benchmarks.dist_parity_probe` by BOTH
 so the two subprocess callers cannot drift apart.
 
 Must run as its own process: the XLA device-count flag only takes
-effect before jax initialises its backends.
+effect before jax initialises its backends. A CPU-only probe: it forces
+JAX_PLATFORMS=cpu, so on a machine with a chip it still measures the
+host CPU (interpret-mode kernels); `chip_smoke.py --four-chips` is its
+chip counterpart.
 """
 import os
 

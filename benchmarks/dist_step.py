@@ -14,16 +14,17 @@ Two kinds of rows:
     (landed PR 4): the jax.random (B, S, K) Gumbel round-trip —
     `sampler_gumbel_bytes`, ~8x the gather traffic at paper shapes —
     drops out of the per-step HBM budget entirely.
-  * measured — dist-vs-single wall time and the parity error on a
-    4-way (2x2) host-CPU mesh, via the shared
+  * measured — a CPU-ONLY PROBE: dist-vs-single wall time and the
+    parity error on a 4-way (2x2) host-CPU mesh, via the shared
     `benchmarks.dist_parity_probe` SUBPROCESS (the same probe the test
-    suite's single-device fallback runs) with
-    XLA_FLAGS=--xla_force_host_platform_device_count=4 so the parent
-    process's jax (already initialised single-device) is untouched.
-    Interpret-mode kernels make absolute times meaningless; the row
-    exists as a tracked end-to-end witness that the dist step runs and
-    matches (parity column), not as a speed claim — real speedups are
-    TPU-only (see ROADMAP: remote-DMA gather follow-on).
+    suite's single-device fallback runs), which forces
+    JAX_PLATFORMS=cpu and XLA_FLAGS=--xla_force_host_platform_device_
+    count=4 so the parent process's jax is untouched. Even on a machine
+    with a chip these rows are host-CPU, interpret-mode numbers (every
+    row says ``probe=cpu-only``): a tracked witness that the dist step
+    runs and matches (parity column), never a device time. The chip
+    counterpart is ``python chip_smoke.py --four-chips`` (the fused
+    step on a 2x2 mesh of four real chips against one chip).
 """
 from __future__ import annotations
 
@@ -72,15 +73,15 @@ def run() -> None:
             timeout=1200,
         )
     except subprocess.TimeoutExpired:
-        emit("dist_step_cpu4", 0.0, "FAILED:timeout after 1200s")
+        emit("dist_step_cpu4", 0.0, "probe=cpu-only;FAILED:timeout after 1200s")
         return
     rows = [ln for ln in res.stdout.splitlines() if ln.startswith("ROW,")]
     if not rows:
-        emit("dist_step_cpu4", 0.0, f"FAILED:{res.stderr[-300:]}")
+        emit("dist_step_cpu4", 0.0, f"probe=cpu-only;FAILED:{res.stderr[-300:]}")
         return
     for ln in rows:
         _, name, us, derived = ln.split(",", 3)
-        emit(name, float(us), derived)
+        emit(name, float(us), f"probe=cpu-only;{derived}")
 
 
 if __name__ == "__main__":

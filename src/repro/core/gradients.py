@@ -129,14 +129,12 @@ def covariance_surrogate(
         # gradients to h only), kernels running per beta shard
         from repro.dist.fopo import dist_fused_covariance_loss
 
-        fused_interpret = resolve_interpret(fused_interpret)
         h = policy.user_embedding(params, x)
         return dist_fused_covariance_loss(
             h, beta, actions, log_q, rewards,
             dist=dist, interpret=fused_interpret, sample_tile=sample_tile,
         )
     if fused:
-        fused_interpret = resolve_interpret(fused_interpret)
         h = policy.user_embedding(params, x)  # [B, L] differentiable
         return fused_covariance_loss(
             h, beta, actions, log_q, rewards,
@@ -215,7 +213,7 @@ def fused_covariance_loss(
     log_q: jnp.ndarray,  # [B, S]; LOG_Q_PAD on masked slots
     rewards: jnp.ndarray,  # [B, S]
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
     sample_tile: int = DEFAULT_SAMPLE_TILE,
 ) -> tuple[jnp.ndarray, dict]:
     """The fused FOPO step: (loss, aux) with a custom VJP whose backward
@@ -230,7 +228,7 @@ def fused_covariance_loss(
     wrt beta returns the true scatter gradient. Do not use ``fused=True``
     to fine-tune item embeddings."""
     return _fused_covariance_loss(
-        interpret, sample_tile, h, beta, actions, log_q, rewards
+        resolve_interpret(interpret), sample_tile, h, beta, actions, log_q, rewards
     )
 
 

@@ -248,8 +248,13 @@ class ExecutionPlan:
       cfg.fused_interpret  -> plan.interpret      compiled Pallas vs
                                                   interpret-mode kernels
       cfg.sample_tile      -> plan.sample_tile    clamped kernel tiling
-      cfg.retriever        -> plan.retriever      built (h, beta)->TopK
-                                                  (None under dist: the
+      cfg.retriever        -> plan.retriever      built (h, beta)->TopK;
+                                                  (h, beta, index)->TopK
+                                                  for ivf_pallas, the
+                                                  index riding as the
+                                                  initial_index_state
+                                                  operand (None under
+                                                  dist: the
                                                   sharded top-K merge
                                                   owns retrieval —
                                                   except "ivf_pallas",
@@ -279,7 +284,13 @@ class ExecutionPlan:
     # so the maintained index rides the step as data (no recompiles as
     # it updates; the trainer owns the state and its refresh cadence).
     refresh: RefreshConfig | None = None
-    initial_index_state: RefreshState | None = None
+    # the index OPERAND of the retriever — (h, beta, index) -> TopK — for
+    # every ivf_pallas plan: the maintained RefreshState under a refresh
+    # plan, else the tile-aligned IVFIndex / ShardedIVFIndex. A closure
+    # capture would embed the whole [C, cap, L] table in the compiled
+    # step as a constant (~GB at paper widths); as an operand it stays a
+    # device buffer. None for retrievers without an index.
+    initial_index_state: RefreshState | Any | None = None
     # the degradation ladder's last rung (repro.health.index_health):
     # a pre-resolved EXACT retriever with the refresh path's
     # (h, beta, state) signature — resolved at construction so the
@@ -400,19 +411,29 @@ class ExecutionPlan:
                 fallback = lambda h, beta, state: dist_sharded_topk(  # noqa: E731
                     h, beta, top_k, dist_cfg, num_items=num_items
                 )
+        elif retriever is None and cfg.retriever == "ivf_pallas":
+            # the static index rides as the retriever's operand (see
+            # `initial_index_state`); under dist= retrieval joins the
+            # plan as a per-shard IVF probe + K-merge instead of the
+            # sharded exact top-K
+            from repro.kernels.ivf_topk import ops as ivf_ops
+
+            initial_state, n_probe, cap_tile = _resolve_ivf_pallas_kwargs(kw)
+            r_interp, dist_cfg, top_k = kw["interpret"], cfg.dist, cfg.top_k
+            if dist_cfg is None:
+                retriever = lambda h, beta, index: ivf_ops.ivf_topk(  # noqa: E731
+                    h, index, top_k, n_probe=n_probe, cap_tile=cap_tile,
+                    interpret=r_interp,
+                )
+            else:
+                from repro.dist.fopo import dist_ivf_topk
+
+                retriever = lambda h, beta, index: dist_ivf_topk(  # noqa: E731
+                    h, index, top_k, dist_cfg, n_probe=n_probe,
+                    cap_tile=cap_tile, interpret=r_interp,
+                )
         elif retriever is None and cfg.dist is None:
             retriever = make_retriever(cfg, **kw)
-        elif retriever is None and cfg.retriever == "ivf_pallas":
-            # dist x ivf_pallas: retrieval joins the plan as a per-shard
-            # IVF probe + K-merge instead of the sharded exact top-K
-            from repro.dist.fopo import dist_ivf_topk
-
-            index, n_probe, cap_tile = _resolve_ivf_pallas_kwargs(kw)
-            r_interp, dist_cfg, top_k = kw["interpret"], cfg.dist, cfg.top_k
-            retriever = lambda h, beta: dist_ivf_topk(  # noqa: E731
-                h, index, top_k, dist_cfg, n_probe=n_probe,
-                cap_tile=cap_tile, interpret=r_interp,
-            )
         return cls(
             cfg=cfg,
             backend=backend,
@@ -509,7 +530,7 @@ class ExecutionPlan:
         from repro.obs.trace import span
 
         with span("retrieval", route=self.cfg.retriever):
-            if self.refresh is not None:
+            if self.initial_index_state is not None:
                 state = (
                     index_state if index_state is not None
                     else self.initial_index_state
@@ -542,15 +563,11 @@ class ExecutionPlan:
     def _draw_uniform(self, key, batch: int) -> "ProposalSample":
         from repro.core.proposals import UniformProposal
 
-        prop = UniformProposal(self.cfg.num_items)
-        if self.dist is None:
-            return prop.sample(key, batch, self.cfg.num_samples)
-        from repro.dist.fopo import _sample_replicated
-
-        return _sample_replicated(
-            self.dist,
-            lambda k: prop.sample(k, batch, self.cfg.num_samples),
-            key,
+        # one jax.random call on one device and on the mesh alike: the
+        # partitionable threefry (jax's default) draws the same values
+        # however the outer jit partitions the sampling ops
+        return UniformProposal(self.cfg.num_items).sample(
+            key, batch, self.cfg.num_samples
         )
 
     def _draw_mixture(self, key, topk: "TopK", eps) -> "ProposalSample":
@@ -577,21 +594,11 @@ class ExecutionPlan:
             )
         from repro.core.proposals import MixtureProposal
 
-        if self.dist is None:
-            # single shared implementation, float or traced epsilon alike
-            return MixtureProposal(cfg.num_items, eps).sample(
-                key, topk.indices, topk.scores, cfg.num_samples
-            )
-        from repro.dist.fopo import _sample_replicated
-
-        # eps rides along as an operand so traced schedules work; the
-        # traced-eps route draws identically to the float one
-        return _sample_replicated(
-            self.dist,
-            lambda k, idx, sc, e: MixtureProposal(cfg.num_items, e).sample(
-                k, idx, sc, cfg.num_samples
-            ),
-            key, topk.indices, topk.scores, jnp.asarray(eps, jnp.float32),
+        # single shared implementation, float or traced epsilon alike, on
+        # one device and on the mesh (partitionable threefry: the draws do
+        # not depend on how the outer jit shards the sampling ops)
+        return MixtureProposal(cfg.num_items, eps).sample(
+            key, topk.indices, topk.scores, cfg.num_samples
         )
 
     # -- weighting + reduction ------------------------------------------

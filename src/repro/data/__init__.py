@@ -9,6 +9,7 @@ from repro.data.synthetic import (
     SessionDataset,
     SyntheticConfig,
     clustered_catalog,
+    clustered_sessions,
     generate_sessions,
     goodreads_like,
     twitch_like,
@@ -18,6 +19,7 @@ __all__ = [
     "SessionDataset",
     "SyntheticConfig",
     "clustered_catalog",
+    "clustered_sessions",
     "generate_sessions",
     "twitch_like",
     "goodreads_like",

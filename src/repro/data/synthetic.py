@@ -193,3 +193,54 @@ def clustered_catalog(
         + std * rng.standard_normal((num_queries, embed_dim))
     )
     return items.astype(np.float32), queries.astype(np.float32)
+
+
+def clustered_sessions(
+    num_items: int,
+    embed_dim: int,
+    num_users: int,
+    *,
+    num_clusters: int,
+    num_positives: int = 8,
+    noise: float = 0.3,
+    seed: int = 0,
+) -> SessionDataset:
+    """A session dataset at any catalog size, made from a seed in
+    O(P * L) vectorised host time — the published-width counterpart of
+    `generate_sessions`, whose per-user O(P) loop and randomized SVD
+    take minutes at P = 750K and whose catalog is lopsided (one k-means
+    cluster takes most items, so the padded IVF lists run to tens of
+    GB).
+
+    Items are ``num_clusters`` unit-norm centers plus Gaussian noise of
+    norm ~``noise``, each cluster holding ~P / C items, so an IVF index
+    with C lists stays balanced. A user's context is one cluster's
+    center plus noise, and its ``num_positives`` completion targets are
+    items of that cluster drawn uniformly (-1 where the cluster is
+    empty): the reward has signal a policy can learn."""
+    rng = np.random.default_rng(seed)
+    scale = noise / np.sqrt(embed_dim)
+    centers = rng.standard_normal((num_clusters, embed_dim), dtype=np.float32)
+    centers /= np.linalg.norm(centers, axis=1, keepdims=True)
+    assign = rng.integers(0, num_clusters, size=num_items)
+    items = centers[assign]
+    items += scale * rng.standard_normal((num_items, embed_dim), dtype=np.float32)
+    user_cluster = rng.integers(0, num_clusters, size=num_users)
+    contexts = centers[user_cluster]
+    contexts += scale * rng.standard_normal((num_users, embed_dim), dtype=np.float32)
+
+    # members of each cluster are a contiguous run of `order`
+    order = np.argsort(assign, kind="stable")
+    counts = np.bincount(assign, minlength=num_clusters)
+    starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
+    size = counts[user_cluster][:, None]
+    pick = starts[user_cluster][:, None] + (
+        rng.random((num_users, num_positives)) * size
+    ).astype(np.int64)
+    positives = np.where(size > 0, order[np.minimum(pick, num_items - 1)], -1)
+    return SessionDataset(
+        contexts=contexts,
+        positives=positives.astype(np.int32),
+        item_embeddings=items,
+        num_items=num_items,
+    )

@@ -56,7 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from repro.backend import resolve_interpret
 from repro.core.policy import SoftmaxPolicy
 from repro.core.proposals import ProposalSample
 from repro.core.snis import snis_covariance_coefficients, snis_diagnostics
@@ -159,7 +159,7 @@ def _dist_scores(dist, interpret, tile, h, beta_p, actions, log_q, rewards):
         )
         return psum_scores(part, dist.model_axis)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=dist.mesh,
         in_specs=(
@@ -187,7 +187,7 @@ def _dist_grad_h(dist, interpret, tile, g_scores, actions, beta_p):
         )
         return jax.lax.psum(part, dist.model_axis)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=dist.mesh,
         in_specs=(
@@ -202,11 +202,12 @@ def _dist_grad_h(dist, interpret, tile, g_scores, actions, beta_p):
 
 def dist_score_partials(
     h, beta, actions, log_q, rewards, *, dist: DistConfig,
-    interpret: bool = True, sample_tile: int = DEFAULT_SAMPLE_TILE,
+    interpret: bool | None = None, sample_tile: int = DEFAULT_SAMPLE_TILE,
 ):
     """Per-shard score partials [n_model, B, S] BEFORE the psum —
     observability hook for tests (e.g. the all-foreign-ids shard must
     be exactly zero) and for debugging ownership masks."""
+    interpret = resolve_interpret(interpret)
     tile = resolve_sample_tile(sample_tile, actions.shape[1])
     beta_p = pad_rows(beta, dist.n_model)
     actions, log_q, rewards = pad_samples(
@@ -218,7 +219,7 @@ def dist_score_partials(
             dist, interpret, tile, h_, beta_sh, acts, lq, rw
         )[None]
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=dist.mesh,
         in_specs=(
@@ -303,7 +304,7 @@ def dist_fused_covariance_loss(
     rewards: jnp.ndarray,  # [B, S]
     *,
     dist: DistConfig,
-    interpret: bool = True,
+    interpret: bool | None = None,
     sample_tile: int = DEFAULT_SAMPLE_TILE,
 ) -> tuple[jnp.ndarray, dict]:
     """The multi-device fused FOPO step: (loss, aux) with a custom VJP
@@ -327,7 +328,7 @@ def dist_fused_covariance_loss(
             actions, log_q, rewards, dist.n_model
         )
     return _dist_covariance_loss(
-        dist, interpret, tile, s, h, beta_p, actions, log_q, rewards
+        dist, resolve_interpret(interpret), tile, s, h, beta_p, actions, log_q, rewards
     )
 
 
@@ -390,7 +391,7 @@ def dist_ivf_topk(
             P(dist.model_axis, None, None, None),
         ]
         operands += [delta[0], delta[1]]
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=dist.mesh,
         in_specs=tuple(in_specs),
@@ -422,7 +423,7 @@ def dist_sharded_topk(
             q, items_sh, k, dist.model_axis, block_items, num_valid
         )
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=dist.mesh,
         in_specs=(P(dist.data_axis, None), P(dist.model_axis, None)),
@@ -431,25 +432,6 @@ def dist_sharded_topk(
         ),
         check_vma=False,
     )(h, beta_p)
-
-
-def _sample_replicated(dist: DistConfig, local_fn, *arrays):
-    """Run the proposal sampling with *replicated* semantics on every
-    device: a shard_map whose specs are all P() pins the jax.random
-    chain to one unpartitioned program per device, so the draws equal
-    the eager / single-device stream bit for bit. Without this, the
-    pre-partitionable threefry (jax_threefry_partitionable=False, the
-    0.4.37 default) silently produces DIFFERENT values when the outer
-    jit partitions the sampling ops over the mesh — same distribution,
-    different trajectory, no error (caught by the dist-vs-single
-    trainer parity test)."""
-    return shard_map(
-        local_fn,
-        mesh=dist.mesh,
-        in_specs=(P(),) * len(arrays),
-        out_specs=ProposalSample(actions=P(), log_q=P(), topk_slot=P()),
-        check_vma=False,
-    )(*arrays)
 
 
 def dist_fused_mixture_sample(
@@ -461,7 +443,7 @@ def dist_fused_mixture_sample(
     num_items: int,
     sample_tile: int,
     dist: DistConfig,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> ProposalSample:
     """The Pallas in-kernel eps-mixture sampler on the mesh: one kernel
     launch per data shard, over that shard's local top-K rows.
@@ -481,6 +463,7 @@ def dist_fused_mixture_sample(
     all-gather/rebase machinery of `dist_fused_covariance_loss` treats
     them exactly like jax.random draws.
     """
+    interpret = resolve_interpret(interpret)
     b = topk.indices.shape[0]
     if b % dist.n_data:
         raise ValueError(
@@ -502,7 +485,7 @@ def dist_fused_mixture_sample(
         )
         return ProposalSample(actions=actions, log_q=log_q, topk_slot=slots)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=dist.mesh,
         in_specs=(P(None), P(None), P(dist.data_axis, None), P(dist.data_axis, None)),
@@ -561,7 +544,7 @@ def dist_verdict_agree(verdict: jnp.ndarray, dist: DistConfig) -> jnp.ndarray:
         v = jax.lax.pmax(v, dist.data_axis)
         return jax.lax.pmax(v, dist.model_axis)
 
-    return shard_map(
+    return jax.shard_map(
         agree,
         mesh=dist.mesh,
         in_specs=P(),

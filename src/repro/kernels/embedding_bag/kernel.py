@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _embedding_bag_kernel(
     idx_ref,  # [B, T] int32 scalar-prefetch (SMEM)
@@ -69,7 +67,7 @@ def embedding_bag_pallas(
         _embedding_bag_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, d), table.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

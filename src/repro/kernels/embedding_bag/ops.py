@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.backend import resolve_interpret
 from repro.kernels.embedding_bag.kernel import embedding_bag_pallas
 from repro.kernels.embedding_bag.ref import embedding_bag_ref
 
@@ -16,8 +17,9 @@ def embedding_bag(
     indices: jnp.ndarray,  # [B, T] int32, -1 padded
     combiner: str = "sum",
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
+    interpret = resolve_interpret(interpret)
     if combiner == "max":  # documented fallback: gather is the hot path
         return embedding_bag_ref(table, indices, combiner="max")
     out = embedding_bag_pallas(table, indices.astype(jnp.int32), interpret=interpret)

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -2.0e38
 
 
@@ -159,7 +157,7 @@ def flash_backward_pallas(
         out_specs=pl.BlockSpec((1, tile_q, dh), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, dh), q.dtype),
         scratch_shapes=[pltpu.VMEM((tile_q, dh), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -188,7 +186,7 @@ def flash_backward_pallas(
             pltpu.VMEM((tile_kv, dh), jnp.float32),
             pltpu.VMEM((tile_kv, dh), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

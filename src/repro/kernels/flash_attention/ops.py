@@ -10,6 +10,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.backend import resolve_interpret
 from repro.kernels.flash_attention.backward import flash_backward_pallas
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 
@@ -71,8 +72,9 @@ def flash_attention(
     q_offset: int = 0,
     tile_q: int = 512,
     tile_kv: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
+    interpret = resolve_interpret(interpret)
     b, sq, h, dh = q.shape
     skv, kv = k.shape[1], k.shape[2]
     n_rep = h // kv

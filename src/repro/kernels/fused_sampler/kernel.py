@@ -60,7 +60,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.constants import LOG_Q_PAD
-from repro.kernels._compat import CompilerParams
 
 # splitmix32 finalizer constants (Steele et al. mix, 32-bit variant)
 _GOLDEN = 0x9E3779B9
@@ -80,8 +79,10 @@ def _hash_u32(seed: jnp.ndarray, ctr: jnp.ndarray) -> jnp.ndarray:
 
 
 def _uniform01(seed: jnp.ndarray, ctr: jnp.ndarray) -> jnp.ndarray:
-    """float32 uniforms in [0, 1) with 24 mantissa bits."""
-    return (_hash_u32(seed, ctr) >> jnp.uint32(8)).astype(jnp.float32) * (
+    """float32 uniforms in [0, 1) with 24 mantissa bits (cast through
+    int32, exact below 2^24: Mosaic has no uint32 -> float32 cast)."""
+    bits = (_hash_u32(seed, ctr) >> jnp.uint32(8)).astype(jnp.int32)
+    return bits.astype(jnp.float32) * (
         1.0 / (1 << 24)
     )
 
@@ -198,27 +199,30 @@ def fused_sampler_pallas(
         num_items=num_items,
         top_k=k,
     )
+    # Mosaic blocks must match (8, 128) or the whole trailing dims, so
+    # the per-row operands carry a unit dim — top-K rows as [B, 1, K],
+    # sample tiles as [B, S/TS, 1, TS] — and the kernel body still sees
+    # (1, K) and (1, TS) blocks; the three scalars ride whole in SMEM.
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    row = pl.BlockSpec((None, 1, k), lambda i, j: (i, 0, 0))
+    tile = pl.BlockSpec((None, None, 1, ts), lambda i, j: (i, j, 0, 0))
     out = pl.pallas_call(
         kernel,
         grid=(b, num_j),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),  # seed
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),  # eps
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),  # row offset
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),  # top-K ids (resident)
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),  # top-K scores
+            smem,  # seed
+            smem,  # eps
+            smem,  # row offset
+            row,  # top-K ids (resident)
+            row,  # top-K scores
         ],
-        out_specs=[
-            pl.BlockSpec((1, ts), lambda i, j: (i, j)),
-            pl.BlockSpec((1, ts), lambda i, j: (i, j)),
-            pl.BlockSpec((1, ts), lambda i, j: (i, j)),
-        ],
+        out_specs=[tile, tile, tile],
         out_shape=[
-            jax.ShapeDtypeStruct((b, sp), jnp.int32),
-            jax.ShapeDtypeStruct((b, sp), jnp.float32),
-            jax.ShapeDtypeStruct((b, sp), jnp.int32),
+            jax.ShapeDtypeStruct((b, num_j, 1, ts), jnp.int32),
+            jax.ShapeDtypeStruct((b, num_j, 1, ts), jnp.float32),
+            jax.ShapeDtypeStruct((b, num_j, 1, ts), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")  # no cross-step state
         ),
         interpret=interpret,
@@ -226,7 +230,7 @@ def fused_sampler_pallas(
         seed.reshape(1, 1).astype(jnp.int32),
         jnp.asarray(epsilon, jnp.float32).reshape(1, 1),
         jnp.asarray(row_offset, jnp.int32).reshape(1, 1),
-        topk_indices.astype(jnp.int32),
-        topk_scores.astype(jnp.float32),
+        topk_indices.astype(jnp.int32).reshape(b, 1, k),
+        topk_scores.astype(jnp.float32).reshape(b, 1, k),
     )
-    return out
+    return [o.reshape(b, sp) for o in out]

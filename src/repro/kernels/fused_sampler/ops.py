@@ -8,9 +8,11 @@ tiled `snis_covgrad` ops is a no-op pad (Sp % TS == 0 already), which
 is the point: step 4 of Algorithm 1 is produced in the layout step 5
 consumes.
 
-`interpret=True` is the CPU fallback: the kernel's PRNG is a plain-jnp
-counter hash precisely so the same kernel body runs under interpret
-mode (see kernel.py) — there is no separate jnp code path to drift.
+`interpret=None` takes the backend rule (compiled on TPU, interpret
+mode elsewhere). Interpret mode is the CPU fallback: the kernel's PRNG
+is a plain-jnp counter hash precisely so the same kernel body runs
+under interpret mode (see kernel.py) — there is no separate jnp code
+path to drift.
 """
 from __future__ import annotations
 
@@ -19,6 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.backend import resolve_interpret
 from repro.kernels.fused_sampler.kernel import fused_sampler_pallas
 
 
@@ -44,7 +47,7 @@ def fused_mixture_sample(
     epsilon,  # float or traced jnp scalar, 0 <= eps < 1
     num_items: int,
     sample_tile: int,
-    interpret: bool = True,
+    interpret: bool | None = None,
     row_offset: int | jnp.ndarray = 0,
 ):
     """Draw S eps-mixture actions per context in-kernel; returns
@@ -53,6 +56,7 @@ def fused_mixture_sample(
     global rows [o, o + B) passes o and draws exactly those rows of
     the full-batch stream (how the dist path keeps per-shard streams
     disjoint AND mesh-shape-reproducible)."""
+    interpret = resolve_interpret(interpret)
     # fold the jax key into the kernel's counter-hash seed; consuming
     # the key here keeps the usual "split per step" discipline upstream
     seed = key_to_seed(key)

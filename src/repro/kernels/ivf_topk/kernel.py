@@ -38,26 +38,54 @@ index_maps.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.constants import NEG_INF
-from repro.kernels._compat import CompilerParams
+
+
+def _bitonic_sort_desc(s, ids):
+    """Sort the (1, N) rows (N a power of 2) by score, descending, with
+    ties broken by lower lane first — exactly `lax.top_k`'s order, which
+    Mosaic cannot lower in-kernel. A bitonic network: log2(N)(log2(N)+1)/2
+    compare-exchange stages, each pairing lane l with lane l ^ j through
+    two lane rotations. The original lane rides along as the tie-break
+    key, so the order is total and the network exact."""
+    n = s.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    pos = lane
+    k = 2
+    while k <= n:
+        j = k // 2
+        while j >= 1:
+            upper = (lane & j) != 0  # partner is lane - j, else lane + j
+
+            def partner(x, j=j, upper=upper):
+                return jnp.where(
+                    upper, pltpu.roll(x, j, 1), pltpu.roll(x, n - j, 1)
+                )
+
+            ps, pi, pp = partner(s), partner(ids), partner(pos)
+            beats = (s > ps) | ((s == ps) & (pos < pp))
+            # the lower lane of a forward block keeps the winner
+            keep = beats == (upper == ((lane & k) != 0))
+            s = jnp.where(keep, s, ps)
+            ids = jnp.where(keep, ids, pi)
+            pos = jnp.where(keep, pos, pp)
+            j //= 2
+        k *= 2
+    return s, ids
 
 
 def _ivf_topk_kernel(
     probe_ref,  # [B, n_probe] int32 scalar-prefetch (SMEM)
     q_ref,  # (1, L) query row b (resident across probe/cap steps)
     ids_tile_ref,  # (1, CT) inverted-list ids of cluster probe[b, jp]
-    emb_tile_ref,  # (1, CT, L) that cluster's embedding tile
-    scores_ref,  # (1, K) running top-K scores (output, accumulated)
-    out_ids_ref,  # (1, K) running top-K ids (output, accumulated)
-    *,
-    k: int,
+    emb_tile_ref,  # (CT, L) that cluster's embedding tile
+    scores_ref,  # (1, KP) running top-KP scores (output, accumulated)
+    out_ids_ref,  # (1, KP) running top-KP ids (output, accumulated)
 ):
     jp = pl.program_id(1)
     jc = pl.program_id(2)
@@ -67,7 +95,7 @@ def _ivf_topk_kernel(
         scores_ref[...] = jnp.full_like(scores_ref, NEG_INF)
         out_ids_ref[...] = jnp.full_like(out_ids_ref, -1)
 
-    tile = emb_tile_ref[0]  # (CT, L)
+    tile = emb_tile_ref[...]  # (CT, L)
     # all CT candidate scores as one contraction against the query row
     s = jax.lax.dot_general(
         q_ref[...], tile, (((1,), (1,)), ((), ())),
@@ -76,11 +104,21 @@ def _ivf_topk_kernel(
     ids = ids_tile_ref[...]  # (1, CT)
     s = jnp.where(ids >= 0, s, NEG_INF)  # list padding is dead
 
-    cat_s = jnp.concatenate([scores_ref[...], s], axis=-1)  # (1, K+CT)
-    cat_i = jnp.concatenate([out_ids_ref[...], ids], axis=-1)
-    new_s, pos = jax.lax.top_k(cat_s, k)
-    scores_ref[...] = new_s
-    out_ids_ref[...] = jnp.take_along_axis(cat_i, pos, axis=-1)
+    # merge: the running top-KP (sorted, so earlier lanes win ties as in
+    # lax.top_k over the concatenation) and the tile, padded with dead
+    # lanes to a power of two, sorted by the bitonic network
+    kp, ct = scores_ref.shape[-1], s.shape[-1]
+    n = pl.next_power_of_2(kp + ct)
+    parts_s = [scores_ref[...], s]
+    parts_i = [out_ids_ref[...], ids]
+    if n > kp + ct:
+        parts_s.append(jnp.full((1, n - kp - ct), NEG_INF, jnp.float32))
+        parts_i.append(jnp.full((1, n - kp - ct), -1, jnp.int32))
+    new_s, new_i = _bitonic_sort_desc(
+        jnp.concatenate(parts_s, axis=-1), jnp.concatenate(parts_i, axis=-1)
+    )
+    scores_ref[...] = new_s[:, :kp]
+    out_ids_ref[...] = new_i[:, :kp]
 
 
 def ivf_topk_pallas(
@@ -98,41 +136,50 @@ def ivf_topk_pallas(
     back-fill score NEG_INF / id -1 (the TopK masking convention)."""
     b, l = queries.shape
     n_probe = probe.shape[1]
-    capp = lists.shape[1]
+    c, capp = lists.shape
     if capp % cap_tile:
         raise ValueError(
             f"cap={capp} must be padded to a multiple of CT={cap_tile}"
         )
+    # the carry is K rounded up to whole 128-lane vregs; its first K
+    # lanes are the top-K (same order), cropped on return
+    kp = -(-k // 128) * 128
     grid = (b, n_probe, capp // cap_tile)
-    kernel = functools.partial(_ivf_topk_kernel, k=k)
+    # Mosaic blocks must match (8, 128) or the whole trailing dims: the
+    # per-row operands carry unit dims ([B, 1, L], [C, capp/CT, 1, CT],
+    # [B, 1, KP]) so the body still sees (1, L), (1, CT), (1, KP) blocks
+    # for any CT (a multiple of 8, for the (CT, L) embedding tile)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, l), lambda i, jp, jc, pr: (i, 0)),  # query row
+            pl.BlockSpec((None, 1, l), lambda i, jp, jc, pr: (i, 0, 0)),  # query row
             # the data-dependent fetch: which cluster's list/embedding
             # tile to DMA comes from the prefetched probe ids
             pl.BlockSpec(
-                (1, cap_tile), lambda i, jp, jc, pr: (pr[i, jp], jc)
+                (None, None, 1, cap_tile),
+                lambda i, jp, jc, pr: (pr[i, jp], jc, 0, 0),
             ),
             pl.BlockSpec(
-                (1, cap_tile, l), lambda i, jp, jc, pr: (pr[i, jp], jc, 0)
+                (None, cap_tile, l), lambda i, jp, jc, pr: (pr[i, jp], jc, 0)
             ),
         ],
         out_specs=[
-            pl.BlockSpec((1, k), lambda i, jp, jc, pr: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, jp, jc, pr: (i, 0)),
+            pl.BlockSpec((None, 1, kp), lambda i, jp, jc, pr: (i, 0, 0)),
+            pl.BlockSpec((None, 1, kp), lambda i, jp, jc, pr: (i, 0, 0)),
         ],
     )
-    return pl.pallas_call(
-        kernel,
+    scores, ids = pl.pallas_call(
+        _ivf_topk_kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, k), jnp.float32),
-            jax.ShapeDtypeStruct((b, k), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, kp), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, kp), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")
         ),
         interpret=interpret,
-    )(probe, queries, lists, list_embs)
+    )(probe, queries.reshape(b, 1, l),
+      lists.reshape(c, capp // cap_tile, 1, cap_tile), list_embs)
+    return scores[:, 0, :k], ids[:, 0, :k]

@@ -9,8 +9,8 @@ evaluates the weights, it does not differentiate them), so
 
 i.e. the backward pass is a coefficient-weighted gather-reduce over the
 same catalog rows the forward pass touched. Like the forward kernel the
-gather happens in-kernel: actions are scalar-prefetched and the beta
-BlockSpec index_map picks the (1, L) row to DMA per grid step — nothing
+gather happens in-kernel: actions are scalar-prefetched and each grid
+step DMAs the (1, L) row they name from HBM into VMEM — nothing
 (B, S, L)-shaped ever reaches HBM, and beta rows are read from HBM
 exactly once per sample.
 
@@ -19,8 +19,8 @@ across the S steps (sequential reduction, "arbitrary"); batch rows
 touch disjoint output blocks, so the B axis is "parallel".
 
 Masked slots (action < 0) carry c == 0 exactly (their SNIS weight is 0)
-and are additionally skipped with pl.when, so the clamped row-0 DMA the
-index_map issues for them never contributes.
+and are additionally skipped with pl.when, so the clamped row-0 DMA
+issued for them never contributes.
 
 `snis_covgrad_bwd_tiled_pallas` is the sample-tiled variant (grid
 (B, Sp/TS)): TS catalog rows are regathered per step with overlapped
@@ -40,21 +40,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
+from repro.kernels.snis_covgrad.kernel import (
+    _elem_spec,
+    _row_spec,
+    _tile_spec,
+    lane_pad,
+)
 
 
 def _fused_bwd_kernel(
     actions_ref,  # [B, S] int32 scalar-prefetch (SMEM)
     coeff_ref,  # (1, 1) dL/df for sample (b, s)
-    beta_ref,  # (1, L) catalog row actions[b, s] (clamped)
+    beta_hbm,  # [P, L] full catalog, memory_space=ANY
     grad_ref,  # (1, L) dL/dh_b accumulator
+    beta_ref,  # (1, L) VMEM row: catalog row actions[b, s] (clamped)
+    sem,  # DMA semaphore of the row copy
 ):
     b = pl.program_id(0)
     s = pl.program_id(1)
+    row = jnp.maximum(actions_ref[b, s], 0)
+    copy = pltpu.make_async_copy(beta_hbm.at[pl.ds(row, 1), :], beta_ref, sem)
+    copy.start()
 
     @pl.when(s == 0)
     def _init():
         grad_ref[...] = jnp.zeros_like(grad_ref)
+
+    copy.wait()
 
     @pl.when(actions_ref[b, s] >= 0)
     def _accum():
@@ -70,26 +82,31 @@ def snis_covgrad_bwd_pallas(
 ) -> jnp.ndarray:
     """grad_h [B, L] = sum_s coeff[b, s] * beta[actions[b, s]]."""
     b, s = actions.shape
+    l0 = beta.shape[-1]
+    beta = lane_pad(beta)
     l = beta.shape[-1]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, s),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, act: (i, j)),  # coeff elem
-            pl.BlockSpec((1, l), lambda i, j, act: (jnp.maximum(act[i, j], 0), 0)),
+            _elem_spec(),  # coeff elem
+            pl.BlockSpec(memory_space=pl.ANY),  # full beta, gathered by DMA
         ],
-        out_specs=pl.BlockSpec((1, l), lambda i, j, act: (i, 0)),
-        scratch_shapes=[],
+        out_specs=_row_spec(l),
+        scratch_shapes=[
+            pltpu.VMEM((1, l), jnp.float32),
+            pltpu.SemaphoreType.DMA,
+        ],
     )
     return pl.pallas_call(
         _fused_bwd_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, l), jnp.float32),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((b, 1, l), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(actions, coeff, beta)
+    )(actions, coeff.reshape(b, s, 1, 1), beta).reshape(b, l)[:, :l0]
 
 
 # ---------------------------------------------------------------------------
@@ -127,11 +144,16 @@ def _fused_bwd_tiled_kernel(
 
     # structural masking: a lane whose action id is negative contributes
     # exactly nothing, whatever coefficient the caller put there
-    valid = jnp.stack(
-        [actions_ref[i, j * sample_tile + u] >= 0 for u in range(sample_tile)]
-    )[None, :]  # (1, TS) bool, built from TS prefetched SMEM scalars
-    coeff = jnp.where(valid, coeff_ref[...], 0.0)  # (1, TS)
-    grad_ref[...] += jnp.dot(coeff, beta_tile[...])  # (1, TS) @ (TS, L)
+    # (the (1, TS) id row is assembled from TS prefetched SMEM scalars by
+    # lane selects: Mosaic cannot stack scalar booleans into a vector)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, sample_tile), 1)
+    acts = jnp.full((1, sample_tile), -1, jnp.int32)
+    for u in range(sample_tile):
+        acts = jnp.where(lane == u, actions_ref[i, j * sample_tile + u], acts)
+    coeff = jnp.where(acts >= 0, coeff_ref[...], 0.0)  # (1, TS)
+    grad_ref[...] += jnp.dot(  # (1, TS) @ (TS, L), full f32 precision
+        coeff, beta_tile[...], precision=jax.lax.Precision.HIGHEST
+    )
 
 
 def snis_covgrad_bwd_tiled_pallas(
@@ -144,18 +166,21 @@ def snis_covgrad_bwd_tiled_pallas(
 ) -> jnp.ndarray:
     """Tiled twin of `snis_covgrad_bwd_pallas`; Sp % sample_tile == 0."""
     b, sp = actions.shape
-    l = beta.shape[-1]
     ts = sample_tile
     if sp % ts:
         raise ValueError(f"S={sp} must be padded to a multiple of TS={ts}")
+    l0 = beta.shape[-1]
+    beta = lane_pad(beta)
+    l = beta.shape[-1]
+    nj = sp // ts
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, sp // ts),
+        grid=(b, nj),
         in_specs=[
-            pl.BlockSpec((1, ts), lambda i, j, act: (i, j)),  # coeff tile
-            pl.BlockSpec(memory_space=pltpu.ANY),  # full beta, DMA-gathered
+            _tile_spec(ts),  # coeff tile
+            pl.BlockSpec(memory_space=pl.ANY),  # full beta, DMA-gathered
         ],
-        out_specs=pl.BlockSpec((1, l), lambda i, j, act: (i, 0)),
+        out_specs=_row_spec(l),
         scratch_shapes=[
             pltpu.VMEM((ts, l), jnp.float32),
             pltpu.SemaphoreType.DMA,
@@ -164,9 +189,9 @@ def snis_covgrad_bwd_tiled_pallas(
     return pl.pallas_call(
         functools.partial(_fused_bwd_tiled_kernel, sample_tile=ts),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, l), jnp.float32),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((b, 1, l), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(actions, coeff, beta)
+    )(actions, coeff.reshape(b, nj, 1, ts), beta).reshape(b, l)[:, :l0]

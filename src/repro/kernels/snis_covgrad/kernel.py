@@ -3,7 +3,7 @@
 Two tilings of the same math live here:
 
 * `snis_covgrad_fwd_pallas` — the per-sample kernel (grid (B, S), one
-  (1, L) beta row DMA'd per step via the scalar-prefetch index_map).
+  (1, L) beta row DMA'd per step from the scalar-prefetched action id).
 * `snis_covgrad_fwd_tiled_pallas` — the sample-tiled kernel (grid
   (B, S/TS)): each step gathers a *tile* of TS catalog rows into a
   (TS, L) VMEM block with explicit overlapped `make_async_copy` DMAs
@@ -27,9 +27,9 @@ Algorithm 1's per-example objective pieces are
 The jnp formulation first materialises the gathered item embeddings
 ``beta[actions]`` — a (B, S, L) tensor — in HBM, then runs the chain as
 five separate ops. Neither kernel lets that tensor exist: the action
-indices are a **scalar-prefetch** operand (SMEM), and either the beta
-BlockSpec's index_map (per-sample kernel) or the in-body async copies
-(tiled kernel) stream exactly the referenced catalog rows HBM -> VMEM.
+indices are a **scalar-prefetch** operand (SMEM), and in-body async
+copies stream exactly the referenced catalog rows HBM -> VMEM (one row
+per step in the per-sample kernel, TS rows per step in the tiled one).
 
 Grids are row-major with the sample axis innermost. Both axes are
 "arbitrary": the softmax over S is computed *online* (flash-attention
@@ -67,9 +67,38 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 from repro.constants import LOG_Q_VALID_MAX, NEG_INF
+
+
+# Mosaic accepts a block only when each of its last two dims is a
+# multiple of (8, 128) or spans the whole array dim. Per-row operands are
+# therefore passed with unit dims inserted — h as [B, 1, L], per-sample
+# elements as [B, S, 1, 1], per-tile lanes as [B, S/TS, 1, TS] — so the
+# kernel body still sees the (1, L), (1, 1) and (1, TS) blocks it was
+# written for, with the leading grid dims squeezed away.
+def _row_spec(l: int) -> pl.BlockSpec:
+    return pl.BlockSpec((None, 1, l), lambda i, j, *_: (i, 0, 0))
+
+
+def _elem_spec() -> pl.BlockSpec:
+    return pl.BlockSpec((None, None, 1, 1), lambda i, j, *_: (i, j, 0, 0))
+
+
+def _tile_spec(ts: int) -> pl.BlockSpec:
+    return pl.BlockSpec((None, None, 1, ts), lambda i, j, *_: (i, j, 0, 0))
+
+
+def lane_pad(x: jnp.ndarray) -> jnp.ndarray:
+    """Zero-pad the last (embedding) dim up to a multiple of 128 lanes.
+
+    A row DMA out of HBM must span whole 128-lane tiles, so the catalog
+    and the user rows enter the kernels lane-padded; the zero lanes add
+    exactly nothing to any score or gradient and are cropped on return.
+    A no-op when L is already a multiple of 128."""
+    pad = (-x.shape[-1]) % 128
+    if not pad:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
 
 
 def _fused_fwd_kernel(
@@ -77,48 +106,58 @@ def _fused_fwd_kernel(
     h_ref,  # (1, L) user embedding row b
     logq_ref,  # (1, 1) log q(a_s|x_b); LOG_Q_PAD on masked slots
     rewards_ref,  # (1, 1)
-    beta_ref,  # (1, L) catalog row actions[b, s] (clamped), DMA'd per step
+    beta_hbm,  # [P, L] full catalog, memory_space=ANY (stays in HBM)
     *refs,
     compute_covgrad: bool,
 ):
-    if not compute_covgrad:  # loss-only trace: score + store, nothing else
-        (scores_ref,) = refs
-        scores_ref[0, 0] = jnp.sum(h_ref[0, :] * beta_ref[0, :])
-        return
-    scores_ref, grad_ref, m_ref, z_ref, r_ref, a_ref, c_ref = refs
+    if compute_covgrad:
+        (scores_ref, grad_ref, beta_ref, sem,
+         m_ref, z_ref, r_ref, a_ref, c_ref) = refs
+    else:
+        scores_ref, beta_ref, sem = refs
+    i = pl.program_id(0)
     s = pl.program_id(1)
     num_s = pl.num_programs(1)
+    # the gather: DMA catalog row actions[b, s] (clamped, so masked -1
+    # never reads out of bounds) into the (1, L) VMEM row
+    row = jnp.maximum(actions_ref[i, s], 0)
+    copy = pltpu.make_async_copy(beta_hbm.at[pl.ds(row, 1), :], beta_ref, sem)
+    copy.start()
+    copy.wait()
+
+    # (1, 1) vector math throughout: Mosaic stores no scalars to VMEM
+    score = jnp.sum(h_ref[...] * beta_ref[...], axis=-1, keepdims=True)
+    scores_ref[...] = score
+    if not compute_covgrad:  # loss-only trace: score + store, nothing else
+        return
 
     @pl.when(s == 0)
     def _init():
-        m_ref[0, 0] = NEG_INF
-        z_ref[0, 0] = 0.0
-        r_ref[0, 0] = 0.0
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        z_ref[...] = jnp.zeros_like(z_ref)
+        r_ref[...] = jnp.zeros_like(r_ref)
         a_ref[...] = jnp.zeros_like(a_ref)
         c_ref[...] = jnp.zeros_like(c_ref)
 
-    score = jnp.sum(h_ref[0, :] * beta_ref[0, :])
-    scores_ref[0, 0] = score
-
-    logq = logq_ref[0, 0]
+    logq = logq_ref[...]
     logw = jnp.where(logq < LOG_Q_VALID_MAX, score - logq, NEG_INF)
-    m_old = m_ref[0, 0]
+    m_old = m_ref[...]
     m_new = jnp.maximum(m_old, logw)
     alpha = jnp.exp(m_old - m_new)  # rescale of everything accumulated so far
     # exact-zero weight on masked slots (robust to all-masked rows where
     # m never leaves the sentinel and exp(logw - m) would be 1, not 0)
     w = jnp.where(logq < LOG_Q_VALID_MAX, jnp.exp(logw - m_new), 0.0)
-    r = rewards_ref[0, 0]
-    z_ref[0, 0] = z_ref[0, 0] * alpha + w
-    r_ref[0, 0] = r_ref[0, 0] * alpha + w * r
-    m_ref[0, 0] = m_new
+    r = rewards_ref[...]
+    z_ref[...] = z_ref[...] * alpha + w
+    r_ref[...] = r_ref[...] * alpha + w * r
+    m_ref[...] = m_new
     a_ref[...] = a_ref[...] * alpha + (w * r) * beta_ref[...]
     c_ref[...] = c_ref[...] * alpha + w * beta_ref[...]
 
     @pl.when(s == num_s - 1)
     def _finalize():
-        z = jnp.maximum(z_ref[0, 0], 1e-30)
-        rbar = r_ref[0, 0] / z
+        z = jnp.maximum(z_ref[...], 1e-30)
+        rbar = r_ref[...] / z
         grad_ref[...] = (a_ref[...] - rbar * c_ref[...]) / z
 
 
@@ -136,19 +175,24 @@ def snis_covgrad_fwd_pallas(
     ``compute_covgrad=False``. The (B, S, L) gathered-embedding tensor
     never exists in HBM — beta rows stream HBM -> VMEM one at a time."""
     b, s = actions.shape
+    l0 = beta.shape[-1]
+    h, beta = lane_pad(h), lane_pad(beta)
     l = beta.shape[-1]
     kernel = functools.partial(_fused_fwd_kernel, compute_covgrad=compute_covgrad)
 
-    out_specs = [pl.BlockSpec((1, 1), lambda i, j, act: (i, j))]  # scores
-    out_shape = [jax.ShapeDtypeStruct((b, s), jnp.float32)]
-    scratch = []  # loss-only trace carries no accumulator state at all
+    out_specs = [_elem_spec()]  # scores
+    out_shape = [jax.ShapeDtypeStruct((b, s, 1, 1), jnp.float32)]
+    scratch = [
+        pltpu.VMEM((1, l), jnp.float32),  # gathered beta row
+        pltpu.SemaphoreType.DMA,
+    ]  # loss-only trace carries no accumulator state beyond the row
     if compute_covgrad:
-        out_specs.append(pl.BlockSpec((1, l), lambda i, j, act: (i, 0)))  # grad
-        out_shape.append(jax.ShapeDtypeStruct((b, l), jnp.float32))
+        out_specs.append(_row_spec(l))  # grad
+        out_shape.append(jax.ShapeDtypeStruct((b, 1, l), jnp.float32))
         scratch += [
-            pltpu.SMEM((1, 1), jnp.float32),  # m — running max
-            pltpu.SMEM((1, 1), jnp.float32),  # z — running normaliser
-            pltpu.SMEM((1, 1), jnp.float32),  # r — running sum w*r
+            pltpu.VMEM((1, 1), jnp.float32),  # m — running max
+            pltpu.VMEM((1, 1), jnp.float32),  # z — running normaliser
+            pltpu.VMEM((1, 1), jnp.float32),  # r — running sum w*r
             pltpu.VMEM((1, l), jnp.float32),  # A — sum w*r*beta
             pltpu.VMEM((1, l), jnp.float32),  # C — sum w*beta
         ]
@@ -157,12 +201,10 @@ def snis_covgrad_fwd_pallas(
         num_scalar_prefetch=1,
         grid=(b, s),
         in_specs=[
-            pl.BlockSpec((1, l), lambda i, j, act: (i, 0)),  # h row (resident)
-            pl.BlockSpec((1, 1), lambda i, j, act: (i, j)),  # log_q elem
-            pl.BlockSpec((1, 1), lambda i, j, act: (i, j)),  # reward elem
-            # the gather: which catalog row to DMA is data-dependent via
-            # the prefetched actions (clamped so masked -1 never DMAs OOB)
-            pl.BlockSpec((1, l), lambda i, j, act: (jnp.maximum(act[i, j], 0), 0)),
+            _row_spec(l),  # h row (resident)
+            _elem_spec(),  # log_q elem
+            _elem_spec(),  # reward elem
+            pl.BlockSpec(memory_space=pl.ANY),  # full beta, gathered by DMA
         ],
         out_specs=out_specs,
         scratch_shapes=scratch,
@@ -171,15 +213,16 @@ def snis_covgrad_fwd_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,
-    )(actions, h, log_q, rewards, beta)
+    )(actions, h.reshape(b, 1, l), log_q.reshape(b, s, 1, 1),
+      rewards.reshape(b, s, 1, 1), beta)
+    scores = out[0].reshape(b, s)
     if compute_covgrad:
-        scores, grad = out
-        return scores, grad
-    return out[0]
+        return scores, out[1].reshape(b, l)[:, :l0]
+    return scores
 
 
 # ---------------------------------------------------------------------------
@@ -245,9 +288,11 @@ def _fused_fwd_tiled_kernel(
     z_ref[0, 0] = z_ref[0, 0] * alpha + jnp.sum(w)
     r_ref[0, 0] = r_ref[0, 0] * alpha + jnp.sum(w * r)
     m_ref[0, 0] = m_new
-    # (1, TS) @ (TS, L) — matmul-shaped accumulator folds, MXU-friendly
-    a_ref[...] = a_ref[...] * alpha + jnp.dot(w * r, tile)
-    c_ref[...] = c_ref[...] * alpha + jnp.dot(w, tile)
+    # (1, TS) @ (TS, L) — matmul-shaped accumulator folds, MXU-friendly,
+    # at full f32 precision (the accumulators are the gradient itself)
+    hi = jax.lax.Precision.HIGHEST
+    a_ref[...] = a_ref[...] * alpha + jnp.dot(w * r, tile, precision=hi)
+    c_ref[...] = c_ref[...] * alpha + jnp.dot(w, tile, precision=hi)
 
     @pl.when(j == num_j - 1)
     def _finalize():
@@ -271,23 +316,26 @@ def snis_covgrad_fwd_tiled_pallas(
     (TS, L) gather tile per step. Requires Sp % sample_tile == 0 (ops.py
     pads); returns (scores [B, Sp], grad [B, L]) or just scores."""
     b, sp = actions.shape
-    l = beta.shape[-1]
     ts = sample_tile
     if sp % ts:
         raise ValueError(f"S={sp} must be padded to a multiple of TS={ts}")
+    l0 = beta.shape[-1]
+    h, beta = lane_pad(h), lane_pad(beta)
+    l = beta.shape[-1]
     kernel = functools.partial(
         _fused_fwd_tiled_kernel, sample_tile=ts, compute_covgrad=compute_covgrad
     )
 
-    out_specs = [pl.BlockSpec((1, ts), lambda i, j, act: (i, j))]  # scores
-    out_shape = [jax.ShapeDtypeStruct((b, sp), jnp.float32)]
+    nj = sp // ts
+    out_specs = [_tile_spec(ts)]  # scores
+    out_shape = [jax.ShapeDtypeStruct((b, nj, 1, ts), jnp.float32)]
     scratch = [
         pltpu.VMEM((ts, l), jnp.float32),  # gathered beta tile
         pltpu.SemaphoreType.DMA,  # shared by the TS in-flight row copies
     ]
     if compute_covgrad:
-        out_specs.append(pl.BlockSpec((1, l), lambda i, j, act: (i, 0)))  # grad
-        out_shape.append(jax.ShapeDtypeStruct((b, l), jnp.float32))
+        out_specs.append(_row_spec(l))  # grad
+        out_shape.append(jax.ShapeDtypeStruct((b, 1, l), jnp.float32))
         scratch += [
             pltpu.SMEM((1, 1), jnp.float32),  # m — running max
             pltpu.SMEM((1, 1), jnp.float32),  # z — running normaliser
@@ -300,12 +348,12 @@ def snis_covgrad_fwd_tiled_pallas(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, sp // ts),
+        grid=(b, nj),
         in_specs=[
-            pl.BlockSpec((1, l), lambda i, j, act: (i, 0)),  # h row (resident)
-            pl.BlockSpec((1, ts), lambda i, j, act: (i, j)),  # log_q tile
-            pl.BlockSpec((1, ts), lambda i, j, act: (i, j)),  # reward tile
-            pl.BlockSpec(memory_space=pltpu.ANY),  # full beta, gathered by DMA
+            _row_spec(l),  # h row (resident)
+            _tile_spec(ts),  # log_q tile
+            _tile_spec(ts),  # reward tile
+            pl.BlockSpec(memory_space=pl.ANY),  # full beta, gathered by DMA
         ],
         out_specs=out_specs,
         scratch_shapes=scratch,
@@ -314,12 +362,13 @@ def snis_covgrad_fwd_tiled_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,
-    )(actions, h, log_q, rewards, beta)
+    )(actions, h.reshape(b, 1, l), log_q.reshape(b, nj, 1, ts),
+      rewards.reshape(b, nj, 1, ts), beta)
+    scores = out[0].reshape(b, sp)
     if compute_covgrad:
-        scores, grad = out
-        return scores, grad
-    return out[0]
+        return scores, out[1].reshape(b, l)[:, :l0]
+    return scores

@@ -9,6 +9,9 @@ S is padded here up to a multiple of TS with dead slots — ``action =
 zero SNIS weight in-kernel, so tails that don't divide the tile are
 bit-for-bit harmless; padded score columns are cropped before return.
 
+``interpret=None`` (the default) takes the backend rule of
+`repro.backend.resolve_interpret`: compiled on TPU, interpret elsewhere.
+
 Masking is by *value*: callers mark dead sample slots with ``action =
 -1`` and ``log_q = LOG_Q_PAD`` (see `repro.constants`). A row whose
 slots are ALL masked produces an exactly-zero gradient row and zero
@@ -21,6 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.backend import resolve_interpret
 from repro.constants import LOG_Q_PAD
 from repro.kernels.snis_covgrad.backward import (
     snis_covgrad_bwd_pallas,
@@ -62,7 +66,7 @@ def snis_covgrad_fused(
     log_q: jnp.ndarray,  # [B, S]; LOG_Q_PAD on masked slots
     rewards: jnp.ndarray,  # [B, S]
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
     sample_tile: int = DEFAULT_SAMPLE_TILE,
 ):
     """Fully fused primal op: in-kernel gather + SNIS + covariance grad.
@@ -72,6 +76,7 @@ def snis_covgrad_fused(
     (B, S) softmax — identical math to the kernel's online normaliser —
     then masked to exact zero on dead slots (all-masked rows included).
     """
+    interpret = resolve_interpret(interpret)
     s = actions.shape[1]
     h32 = h.astype(jnp.float32)
     beta32 = beta.astype(jnp.float32)
@@ -108,11 +113,12 @@ def snis_scores_fused(
     log_q: jnp.ndarray,
     rewards: jnp.ndarray,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
     sample_tile: int = DEFAULT_SAMPLE_TILE,
 ) -> jnp.ndarray:
     """Loss-only forward: sampled scores [B, S] with in-kernel gather,
     skipping the covariance-gradient accumulators (custom_vjp fwd)."""
+    interpret = resolve_interpret(interpret)
     s = actions.shape[1]
     h32 = h.astype(jnp.float32)
     beta32 = beta.astype(jnp.float32)
@@ -144,11 +150,12 @@ def snis_covgrad_bwd(
     actions: jnp.ndarray,  # [B, S] int32
     beta: jnp.ndarray,  # [P, L]
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
     sample_tile: int = DEFAULT_SAMPLE_TILE,
 ) -> jnp.ndarray:
     """grad_h [B, L] = sum_s coeff[b, s] beta[actions[b, s]] — the
     backward gather-reduce (see backward.py)."""
+    interpret = resolve_interpret(interpret)
     s = actions.shape[1]
     cf = coeff.astype(jnp.float32)
     acts = actions.astype(jnp.int32)

@@ -25,7 +25,6 @@ import traceback  # noqa: E402
 
 import jax  # noqa: E402
 
-from repro.compat import set_mesh  # noqa: E402
 from repro.configs import ARCH_IDS, get_arch  # noqa: E402
 from repro.launch import costs, jaxpr_cost  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
@@ -50,7 +49,7 @@ def run_cell(arch_id: str, shape_name: str, *, multi_pod: bool, opt: bool = Fals
         else None,
         donate_argnums=prog.donate_argnums,
     )
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*prog.args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

@@ -7,16 +7,25 @@ Multi-pod: 2 pods x 256 = 512 chips with a leading pure-DP `pod` axis.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _make_mesh(shape, axes):
+    """`jax.make_mesh` with `Auto` axes: the dist code places its
+    operands with explicit `shard_map` specs and NamedShardings, which
+    is the Auto contract (jax now defaults new meshes to Explicit)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 2, model: int = 2, *, pod: int = 0):
-    """Small mesh for CPU-device tests (requires >= data*model devices)."""
+    """Small mesh (requires >= data*model devices): forced CPU host
+    devices in tests, the four chips of one host on a TPU."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _make_mesh((pod, data, model), ("pod", "data", "model"))
+    return _make_mesh((data, model), ("data", "model"))

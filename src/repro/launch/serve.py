@@ -6,6 +6,11 @@ same query-only plan path.
     PYTHONPATH=src python -m repro.launch.serve --arch sasrec --requests 64
     PYTHONPATH=src python -m repro.launch.serve --arch din --requests 16
     PYTHONPATH=src python -m repro.launch.serve --arch gemma2-2b --requests 8
+    PYTHONPATH=src python -m repro.launch.serve --arch sasrec --scale full
+
+``--scale full`` serves the arch's published ``CONFIG`` (sasrec: a
+1,000,000-item catalog, d=50) instead of ``SMOKE_CONFIG``; the weights
+are random, made from a fixed seed.
 
 Requests are enqueued on a virtual arrival clock (``--qps`` spaces
 them; 0 = all at once, the closed-loop shape) and coalesced into padded
@@ -33,11 +38,13 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_arch
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.train import arch_config
 
 
 def build_route(mod, args, rng):
     """Resolve the arch's serving route + a payload generator."""
-    cfg = mod.SMOKE_CONFIG
+    cfg = arch_config(mod, args.scale)
     if mod.FAMILY == "lm":
         from repro.models import lm
         from repro.serve import LMGenerateRoute
@@ -86,13 +93,11 @@ def build_route(mod, args, rng):
     return cfg, route, payload
 
 
-def main() -> None:
-    from repro.obs.report import percentile
-    from repro.obs.run import ObsConfig, ObsRun
-    from repro.serve import CoalescePolicy, ServingEngine
-
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCH_IDS)
+    ap.add_argument("--scale", choices=("smoke", "full"), default="smoke",
+                    help="smoke: SMOKE_CONFIG; full: the published CONFIG")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--qps", type=float, default=0.0,
                     help="offered arrival rate (0 = all at t=0, closed loop)")
@@ -111,7 +116,16 @@ def main() -> None:
                          "--replicas >= 2)")
     ap.add_argument("--obs-dir", default=None,
                     help="write metrics.jsonl + trace.json here")
-    args = ap.parse_args()
+    return ap
+
+
+def main() -> None:
+    from repro.obs.report import percentile
+    from repro.obs.run import ObsConfig, ObsRun
+    from repro.serve import CoalescePolicy, ServingEngine
+
+    args = make_parser().parse_args()
+    enable_compile_cache()
     if args.replicas < 1:
         raise SystemExit("--replicas must be >= 1")
     if args.chaos and args.replicas < 2:

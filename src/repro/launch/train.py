@@ -1,10 +1,18 @@
 """Training launcher: --arch <id> resolves a pool config and runs its
-training step at smoke scale on the local device (CPU container), or
-prints the production launch plan for the real mesh.
+training step on the local device — at smoke scale by default
+(``SMOKE_CONFIG``), or with ``--scale full`` at the arch's published
+widths (``CONFIG``).
 
     PYTHONPATH=src python -m repro.launch.train --arch sasrec --steps 20
     PYTHONPATH=src python -m repro.launch.train --arch granite-8b --steps 5
     PYTHONPATH=src python -m repro.launch.train --arch fopo-paper --steps 200
+    PYTHONPATH=src python -m repro.launch.train --arch fopo-paper --scale full --steps 5
+
+``--scale full`` for fopo-paper trains the paper's shapes (P=750,000,
+L=100, B=32, S=1000, K=256) on the fused main path — Pallas covgrad
+kernels, the in-kernel sampler and `ivf_topk` retrieval over a
+`build_ivf` index — with data made from ``--seed`` by
+`clustered_sessions` (balanced catalog, seconds of host time).
 
 The production path (256/512 chips) reuses the exact same step
 functions through launch/specs.py — the dry-run proves those lower and
@@ -13,6 +21,7 @@ compile on the full meshes.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -20,13 +29,21 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import adam
 
+# users in the seeded published-width dataset (contexts of the batches)
+FULL_NUM_USERS = 2048
 
-def _train_lm(mod, steps: int) -> None:
+
+def arch_config(mod, scale: str):
+    """The arch's ``CONFIG`` (published widths) or ``SMOKE_CONFIG``."""
+    return mod.CONFIG if scale == "full" else mod.SMOKE_CONFIG
+
+
+def _train_lm(cfg, steps: int) -> None:
     from repro.models import lm
 
-    cfg = mod.SMOKE_CONFIG
     params = lm.init_params(cfg, jax.random.PRNGKey(0))
     opt = adam(1e-3)
     step = jax.jit(lm.make_train_step(cfg, opt))
@@ -41,11 +58,10 @@ def _train_lm(mod, steps: int) -> None:
         print(f"step {i}: loss={float(loss):.4f} ({(time.perf_counter()-t0)*1e3:.0f} ms)")
 
 
-def _train_gnn(mod, steps: int) -> None:
+def _train_gnn(cfg, steps: int) -> None:
     from repro.data import random_graph
     from repro.models import gnn
 
-    cfg = mod.SMOKE_CONFIG
     g = random_graph(512, avg_degree=8, seed=0)
     d_feat = 16
     params = gnn.init_params(cfg, jax.random.PRNGKey(0), d_feat=d_feat)
@@ -63,10 +79,9 @@ def _train_gnn(mod, steps: int) -> None:
         print(f"step {i}: loss={float(loss):.4f}")
 
 
-def _train_recsys(mod, steps: int) -> None:
+def _train_recsys(cfg, steps: int) -> None:
     from repro.models import recsys
 
-    cfg = mod.SMOKE_CONFIG
     params = recsys.init_params(cfg, jax.random.PRNGKey(0))
     objective = "fopo" if cfg.kind == "sasrec" else "bce"
     opt = adam(1e-3)
@@ -96,43 +111,94 @@ def _train_recsys(mod, steps: int) -> None:
         print(f"step {i}: loss={float(loss):.5f} [{objective}]")
 
 
-def _train_fopo_paper(mod, steps: int) -> None:
-    from repro.core import FOPOConfig
+def fopo_paper_trainer(
+    cfg, *, seed: int = 0, fused: bool = True, fused_sampler: bool = True
+):
+    """The published-width fopo-paper trainer on the fused main path:
+    `clustered_sessions` data from ``seed``, a `build_ivf` index over
+    the catalog, and ``cfg.fopo`` with retriever="ivf_pallas" plus the
+    fused covgrad kernels / in-kernel sampler. Returns (trainer,
+    train_ds, test_ds)."""
+    from repro.data import clustered_sessions
+    from repro.mips.ivf import build_ivf
+    from repro.train import FOPOTrainer, TrainerConfig
+
+    index_key = jax.random.PRNGKey(seed)
+    num_clusters = max(1, int(2 ** round(np.log2(np.sqrt(cfg.num_items)))))
+    # 64 targets per session: at eps=0.8 the uniform arm alone lands ~0.07
+    # of them per row, so every batch of 32 carries reward
+    data = clustered_sessions(
+        cfg.num_items, cfg.embed_dim, FULL_NUM_USERS,
+        num_clusters=num_clusters, num_positives=64, seed=seed,
+    )
+    train_ds, test_ds = data.split(0.9, seed=seed)
+    index = build_ivf(
+        index_key, jnp.asarray(train_ds.item_embeddings),
+        num_clusters=num_clusters, cap_tile=256,
+    )
+    fopo = dataclasses.replace(
+        cfg.fopo, retriever="ivf_pallas", fused=fused,
+        fused_sampler=fused_sampler,
+    )
+    trainer = FOPOTrainer(
+        TrainerConfig(
+            estimator="fopo", fopo=fopo, batch_size=cfg.batch_size,
+            learning_rate=cfg.learning_rate, checkpoint_every=0, seed=seed,
+        ),
+        train_ds,
+        retriever_kwargs={"index": index, "cap_tile": 256},
+    )
+    return trainer, train_ds, test_ds
+
+
+def _train_fopo_paper(cfg, steps: int, scale: str, seed: int) -> None:
     from repro.data import SyntheticConfig, generate_sessions
     from repro.train import FOPOTrainer, TrainerConfig
 
-    cfg = mod.SMOKE_CONFIG
-    data = generate_sessions(
-        SyntheticConfig(num_items=cfg.num_items, num_users=2000,
-                        embed_dim=cfg.embed_dim, session_len=16)
-    )
-    train_ds, test_ds = data.split(0.9)
-    tr = FOPOTrainer(
-        TrainerConfig(estimator="fopo", fopo=cfg.fopo, batch_size=32,
-                      learning_rate=3e-3, num_steps=steps),
-        train_ds,
-    )
+    if scale == "full":
+        tr, _, test_ds = fopo_paper_trainer(cfg, seed=seed)
+    else:
+        data = generate_sessions(
+            SyntheticConfig(num_items=cfg.num_items, num_users=2000,
+                            embed_dim=cfg.embed_dim, session_len=16,
+                            seed=seed)
+        )
+        train_ds, test_ds = data.split(0.9)
+        tr = FOPOTrainer(
+            TrainerConfig(estimator="fopo", fopo=cfg.fopo, batch_size=32,
+                          learning_rate=3e-3, num_steps=steps),
+            train_ds,
+        )
     print(f"R_test before: {tr.evaluate(test_ds):.4f}")
     tr.train(steps, log_every=max(1, steps // 5))
     print(f"R_test after:  {tr.evaluate(test_ds):.4f}")
 
 
-def main() -> None:
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCH_IDS)
     ap.add_argument("--steps", type=int, default=20)
-    args = ap.parse_args()
+    ap.add_argument("--scale", choices=("smoke", "full"), default="smoke",
+                    help="smoke: SMOKE_CONFIG; full: the published CONFIG")
+    ap.add_argument("--seed", type=int, default=0)
+    return ap
+
+
+def main() -> None:
+    args = make_parser().parse_args()
+    enable_compile_cache()
     mod = get_arch(args.arch)
-    print(f"arch={args.arch} family={mod.FAMILY} (smoke-scale on "
+    cfg = arch_config(mod, args.scale)
+    print(f"arch={args.arch} family={mod.FAMILY} ({args.scale} scale on "
           f"{jax.devices()[0].platform}; production mesh via launch/dryrun.py)")
     if mod.FAMILY == "lm":
-        _train_lm(mod, args.steps)
+        _train_lm(cfg, args.steps)
     elif mod.FAMILY == "gnn":
-        _train_gnn(mod, args.steps)
+        _train_gnn(cfg, args.steps)
     elif mod.FAMILY == "recsys":
-        _train_recsys(mod, args.steps)
+        _train_recsys(cfg, args.steps)
     else:
-        _train_fopo_paper(mod, args.steps)
+        _train_fopo_paper(cfg, args.steps, args.scale, args.seed)
 
 
 if __name__ == "__main__":

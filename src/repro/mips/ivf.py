@@ -45,10 +45,10 @@ def resolve_cap_tile(cap_tile: int | None, cap: int) -> int:
     """THE cap-tile rule, shared by `build_ivf`'s tile-aligned layout
     and the Pallas query wrapper (`repro.kernels.ivf_topk.ops`) so the
     no-repad contract between them cannot drift: clamp to the list
-    capacity, then round down to a multiple of 8 — the kernel's (1, CT)
-    merge runs on the minor axis and Mosaic's native top_k/sort
-    lowering wants sublane-aligned tiles (interpret mode doesn't care,
-    compiled TPU does). Widths below 8 pass through (toy shapes)."""
+    capacity, then round down to a multiple of 8 — the kernel DMAs
+    (CT, L) embedding tiles, whose sublane dim the TPU compiler wants
+    8-aligned (interpret mode doesn't care). Widths below 8 pass through
+    (toy shapes)."""
     ct = min(cap_tile or DEFAULT_CAP_TILE, cap)
     if ct >= 8:
         ct -= ct % 8

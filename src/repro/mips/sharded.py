@@ -16,9 +16,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-
-from repro.compat import axis_size as compat_axis_size
-from repro.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.mips.exact import TopK, merge_topk
@@ -61,7 +58,7 @@ def sharded_topk(
     candidate set before masking), then ids >= num_valid are demoted to
     score NEG_INF / id -1 before the merge — so pad rows never displace
     real items from the global top-K."""
-    n = compat_axis_size(axis)
+    n = jax.lax.axis_size(axis)
     shard_id = jax.lax.axis_index(axis)
     rows = items_shard.shape[0]
     local_k = k
@@ -85,7 +82,7 @@ def make_sharded_topk_fn(mesh, k: int, axis: str = "model", block_items: int = 4
     row-sharded over `axis` and queries/results replicated along it."""
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P(axis, None)),
         out_specs=TopK(scores=P(), indices=P()),
@@ -119,7 +116,7 @@ def context_sharded_topk(
     def fn(q_, it_):
         return sharded_topk(q_, it_, k, item_axis, block_items, num_valid)
 
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,  # None -> the ambient mesh (`with mesh:` context)
         in_specs=(P(batch_axes, None), P(item_axis, None)),
@@ -135,7 +132,7 @@ def sharded_gather_rows(
 ) -> jnp.ndarray:
     """Replicated gather from a row-sharded table: mask + local take + psum.
     The workhorse for sharded beta lookups and sharded embedding tables."""
-    n = compat_axis_size(axis)
+    n = jax.lax.axis_size(axis)
     shard_id = jax.lax.axis_index(axis)
     rows = table_shard.shape[0]
     local_ids = ids - shard_id * rows

@@ -20,8 +20,6 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
-
 from repro.models.attention import decode_attention, flash_attention
 from repro.models.configs_base import LMConfig
 from repro.models.layers import rms_norm, rope, softcap
@@ -133,10 +131,10 @@ def _self_attention(cfg: LMConfig, q, k_, v_, *, window):
         def local_u(q_, k2, v2):
             return fa_ops.flash_attention(
                 q_, k2, v2, causal=True, window=window,
-                logit_cap=cfg.attn_logit_softcap, interpret=True,
+                logit_cap=cfg.attn_logit_softcap,
             )
 
-        return shard_map(
+        return jax.shard_map(
             local_u, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False,
         )(q, k_, v_)
@@ -160,13 +158,13 @@ def _self_attention(cfg: LMConfig, q, k_, v_, *, window):
     def local(q_, k2, v2):
         return fa_ops.flash_attention(
             q_, k2, v2, causal=True, window=window,
-            logit_cap=cfg.attn_logit_softcap, interpret=True,
+            logit_cap=cfg.attn_logit_softcap,
         )
 
     if axes is None:
         return unfold(local(fold(q), fold(k_), fold(v_)))
     spec = P(axes, None, None, None)
-    out = shard_map(
+    out = jax.shard_map(
         local, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
     )(fold(q), fold(k_), fold(v_))
     return unfold(out)

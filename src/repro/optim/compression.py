@@ -17,8 +17,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size as compat_axis_size
-
 
 def quantize_int8(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     scale = jnp.max(jnp.abs(x)) / 127.0 + 1e-12
@@ -41,7 +39,7 @@ def compressed_psum(grads: Any, axis_name: str) -> Any:
         # each shard used its own scale; sum of per-shard maxima is an upper
         # bound — use mean scale for an unbiased-ish reconstruction
         scale_sum = jax.lax.psum(scale, axis_name)
-        n = compat_axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
         return q_sum.astype(jnp.float32) * (scale_sum / n)
 
     return jax.tree.map(one, grads)

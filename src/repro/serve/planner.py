@@ -88,9 +88,9 @@ class QueryPlanner:
             fcfg, retriever_kwargs={"index": index, "n_probe": self.n_probe}
         )
         self.index_state = self.plan.initial_index_state
-        self._fallback_plan = self.plan.degrade_to_fallback()
+        self.fallback_plan = self.plan.degrade_to_fallback()
         self._primary = self._jit(self.plan)
-        self._fallback = self._jit(self._fallback_plan)
+        self._fallback = self._jit(self.fallback_plan)
         self._fn = self._primary
         self._heal_fns = {
             "compact": jax.jit(refresh_mod.compact),
@@ -111,6 +111,12 @@ class QueryPlanner:
     def query(self, x: jnp.ndarray):
         """(x [B, Dx]) -> TopK, dispatched async — the caller blocks."""
         return self._fn(self.params, x, self.beta, self.index_state)
+
+    def query_exact(self, x: jnp.ndarray):
+        """(x [B, Dx]) -> TopK through the pre-resolved exact fallback
+        plan, whatever the live plan is — the reference a recall check
+        holds the live index to (same jitted trace `degrade()` swaps in)."""
+        return self._fallback(self.params, x, self.beta, self.index_state)
 
     def warmup(self, x_example: jnp.ndarray) -> None:
         """Compile the primary AND fallback query paths before traffic:
@@ -150,5 +156,5 @@ class QueryPlanner:
     def degrade(self) -> None:
         """The ladder's last rung: swap to the pre-resolved (and
         pre-warmed) exact-fallback plan. Idempotent."""
-        self.plan = self._fallback_plan
+        self.plan = self.fallback_plan
         self._fn = self._fallback

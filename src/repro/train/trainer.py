@@ -137,10 +137,13 @@ class FOPOTrainer:
         # plan built the initial RefreshState from the caller's index;
         # the trainer owns it from here and dispatches the jitted
         # maintenance ops asynchronously between steps (see train())
+        # the retriever's index operand (None without an indexed
+        # retriever); only a refresh plan maintains — and checkpoints — it
         self.index_state = (
             self.plan.initial_index_state if self.plan is not None else None
         )
-        self._refresh_fns = self._build_refresh() if self.index_state is not None else None
+        self._maintained = self.plan is not None and self.plan.refresh is not None
+        self._refresh_fns = self._build_refresh() if self._maintained else None
         self._refresh_key = jax.random.PRNGKey(cfg.seed + 31)
         # the training RNG is OWNED (not a train()-local): it rides the
         # checkpoint, so a killed-and-resumed run continues the exact
@@ -342,7 +345,7 @@ class FOPOTrainer:
         which is exactly why it is periodic, not per-step). Observations
         land on the metrics bus as index_health events."""
         monitor = self._monitor
-        if monitor is None or self._degraded or self.index_state is None:
+        if monitor is None or self._degraded or not self._maintained:
             return
         ih = monitor.cfg
         cadence = ih.probe_every if ih.probe_every else 1
@@ -435,7 +438,7 @@ class FOPOTrainer:
             "train_key": self._train_key,
             "refresh_key": self._refresh_key,
         }
-        if self.index_state is not None:
+        if self._maintained:
             state["index_state"] = self.index_state
         if self.guard_state is not None:
             state["guard_state"] = self.guard_state

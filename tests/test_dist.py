@@ -305,11 +305,12 @@ def test_covariance_surrogate_dist_kwarg(dist22):
 @multi_device
 def test_dist_trainer_trajectory_matches_single_device(dist22):
     """The jitted dist trainer walks the same parameter trajectory as
-    the single-device fused trainer (same seeds/data). Regression for
-    the pre-partitionable-threefry trap: under the trainer's jit, the
-    partitioner resharding the sampling ops silently changed the drawn
-    actions (same distribution, different trajectory) until the dist
-    path pinned sampling to replicated semantics."""
+    the single-device fused trainer (same seeds/data). Guards the
+    sampling contract: the dist path draws with plain jax.random under
+    the trainer's jit, so the draws must not change when the partitioner
+    reshards the sampling ops (jax's partitionable threefry) — a
+    changed stream shows up as the same distribution on a different
+    trajectory, with no error."""
     import dataclasses
 
     from repro.core.fopo import FOPOConfig

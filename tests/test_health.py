@@ -94,9 +94,13 @@ def make_refresh_trainer(ds, health=None, fault=None, *, steps=6,
     from repro.mips.ivf import build_ivf
 
     items = jnp.asarray(ds.item_embeddings)
+    # cap=None sizes the lists to the largest cluster, so the index holds
+    # every item: this catalog is lopsided (one k-means cluster takes
+    # most of the 300 items), and a fixed cap below that cluster's size
+    # would silently drop its overflow from the lists
     index = build_ivf(
-        jax.random.PRNGKey(1), items, num_clusters=8, cap=128,
-        kmeans_iters=3, cap_tile=32,
+        jax.random.PRNGKey(1), items, num_clusters=8, kmeans_iters=3,
+        cap_tile=32,
     )
     fopo = FOPOConfig(
         num_items=300, num_samples=32, top_k=16, epsilon=0.8,

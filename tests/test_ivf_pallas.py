@@ -322,3 +322,21 @@ print("DIST_IVF_OK")
         timeout=600,
     )
     assert "DIST_IVF_OK" in res.stdout, res.stderr[-3000:]
+
+
+@pytest.mark.parametrize("n", [2, 8, 64, 512])
+def test_bitonic_merge_matches_lax_top_k_order_with_ties(n):
+    """The in-kernel merge network (Mosaic has no lax.top_k lowering)
+    sorts exactly as lax.top_k: descending, ties to the lower lane —
+    checked on integer-valued scores, so ties are everywhere."""
+    from repro.kernels.ivf_topk.kernel import _bitonic_sort_desc
+
+    rng = np.random.default_rng(n)
+    s = jnp.asarray(rng.integers(-4, 4, (1, n)).astype(np.float32))
+    ids = jnp.asarray(rng.permutation(n).astype(np.int32)[None, :])
+    got_s, got_i = jax.jit(_bitonic_sort_desc)(s, ids)
+    want_s, pos = jax.lax.top_k(s, n)
+    np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
+    np.testing.assert_array_equal(
+        np.asarray(got_i), np.asarray(jnp.take_along_axis(ids, pos, axis=-1))
+    )

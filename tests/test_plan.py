@@ -51,6 +51,64 @@ def test_resolve_tpu_backend_selects_compiled_kernels():
     assert resolve_interpret(False, "cpu") is False
 
 
+@pytest.mark.parametrize("route", ["ivf_pallas", "refresh", "fallback"])
+def test_tpu_plan_hands_compiled_mode_to_every_kernel(route, monkeypatch):
+    """On backend="tpu" every Pallas kernel the step traces — covgrad
+    forward and backward, the in-kernel sampler and the ivf_topk
+    retriever — is handed interpret=False, on the plain ivf_pallas plan,
+    the refresh plan and its degraded exact-fallback plan alike. The
+    step is only traced (eval_shape), so nothing is lowered on CPU."""
+    from jax.experimental import pallas as pl
+
+    from repro.mips.ivf import build_ivf
+    from repro.mips.refresh import RefreshConfig
+
+    seen = []
+    real_pallas_call = pl.pallas_call
+
+    def spy(kernel, *args, **kwargs):
+        name = getattr(kernel, "__name__", None) or kernel.func.__name__
+        seen.append((name, kwargs.get("interpret")))
+        return real_pallas_call(kernel, *args, **kwargs)
+
+    monkeypatch.setattr(pl, "pallas_call", spy)
+    jax.clear_caches()  # no cached trace may skip the spy
+
+    policy, params, x, beta, reward_fn = _fopo_problem(seed=4, b=4, l=12, p=160)
+    index = build_ivf(jax.random.PRNGKey(0), beta, num_clusters=4, cap_tile=8)
+    cfg = FOPOConfig(
+        num_items=160, num_samples=20, top_k=8, epsilon=0.5,
+        retriever="ivf_pallas", fused=True, fused_sampler=True, sample_tile=8,
+        index_refresh=None if route == "ivf_pallas" else RefreshConfig(
+            every=0, compact_every=0, delta_cap=8
+        ),
+    )
+    plan = ExecutionPlan.resolve(
+        cfg, backend="tpu", retriever_kwargs={"index": index, "n_probe": 2}
+    )
+    if route == "fallback":
+        plan = plan.degrade_to_fallback()
+    assert plan.interpret is False and plan.cfg.fused_interpret is False
+    key = jax.random.PRNGKey(0)
+    jax.eval_shape(
+        jax.grad(
+            lambda pp: plan.execute(policy, pp, key, x, beta, reward_fn)[0]
+        ),
+        params,
+    )
+    kernels = {name for name, _ in seen}
+    expected = {
+        "_fused_sampler_kernel", "_fused_fwd_tiled_kernel",
+        "_fused_bwd_tiled_kernel",
+    }
+    if route != "fallback":
+        expected.add("_ivf_topk_kernel")
+    else:
+        assert "_ivf_topk_kernel" not in kernels  # exact top-K retrieval
+    assert expected <= kernels, kernels
+    assert all(mode is False for _, mode in seen), seen
+
+
 def test_resolve_leaves_unfused_config_untouched():
     """The unfused jnp path never resolved fused_interpret before; the
     plan keeps that contract (cfg round-trips unchanged)."""

@@ -134,6 +134,23 @@ def test_engine_records_and_occupancy():
     assert recs[4].launch >= recs[0].finish
 
 
+def test_query_exact_is_exact_top_k_of_the_user_tower():
+    """The planner's exact-fallback query — the reference a recall check
+    holds the live index to — is exact top-k over the whole catalog,
+    and leaves the live (IVF) plan in place."""
+    from repro.mips import topk_exact
+
+    cfg, params = _sasrec()
+    route = RecsysMIPSRoute(cfg, params, k=8)
+    planner = route.planner
+    x = jax.numpy.asarray(np.stack(_hists(cfg, 4)))
+    got = planner.query_exact(x)
+    h = planner.policy.user_embedding(params, x)
+    want = topk_exact(h, params["items"], 8)
+    np.testing.assert_array_equal(np.asarray(got.indices), np.asarray(want.indices))
+    assert not planner.degraded and planner.fallback_plan.degraded
+
+
 def test_submit_rejects_decreasing_arrivals():
     cfg, params = _sasrec()
     eng = ServingEngine(RecsysMIPSRoute(cfg, params, k=4))

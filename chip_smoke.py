@@ -16,12 +16,15 @@ the published widths of two configurations:
   (b) parity — one step with fused=True against fused=False (both with
                the jax.random sampler), same key, same ivf_pallas
                retrieval, matmul precision pinned to "highest": loss and
-               tower grad within the bounds of tests/test_fused_step.py.
+               tower grad within the bounds of tests/test_fused_step.py,
+               the grad's absolute bound scaled to the grad's own size.
   (c) serve  — sasrec CONFIG (1,000,000 items, d=50, seq_len=50, two
                blocks) through `ServingEngine` + `RecsysMIPSRoute`:
                every request answered with valid ids, the plan never
-               degraded; prints recall@k against the planner's exact
-               fallback plan on the same requests.
+               degraded; the `ivf_topk` kernel route must return the
+               ids of the planner's jnp IVF reference (same index, same
+               n_probe) on the same requests; prints recall@k against
+               the planner's exact fallback plan.
 
 ``--four-chips`` runs only the fopo-paper fused step (retrieval, the
 in-kernel sampler, the covgrad kernels) on a 2x2 (data x model) mesh of
@@ -51,6 +54,8 @@ SERVE_REQUESTS, SERVE_MAX_BATCH, SERVE_K = 16, 8, 10
 FUSED_LOSS_RTOL, FUSED_GRAD_RTOL, FUSED_GRAD_ATOL = 1e-5, 1e-5, 1e-5
 # tests/test_dist.py (the dist parity probe): mesh vs one device
 DIST_LOSS_RTOL, DIST_GRAD_RTOL, DIST_GRAD_ATOL = 1e-5, 1e-5, 1e-6
+# kernel route vs jnp IVF reference, both at matmul precision "highest"
+SERVE_SCORE_RTOL = 1e-5
 
 
 def dense_reward(actions):
@@ -76,6 +81,27 @@ def _compiled(plan, what: str) -> None:
 
 def _rel(a: float, b: float) -> float:
     return abs(a - b) / max(abs(b), 1e-30)
+
+
+def _grads_close(got, want, rtol: float, atol: float) -> tuple[float, float, float]:
+    """Assert two grad pytrees agree within rtol and an absolute bound of
+    ``atol`` or ``rtol`` times the reference grad's largest entry,
+    whichever is smaller: at the smoke's small losses the grads can sit
+    below a fixed atol, which would then accept any values. Returns
+    (max |diff|, max |reference grad|, the absolute bound used)."""
+    import jax
+    import numpy as np
+
+    want = [np.asarray(b) for b in jax.tree.leaves(want)]
+    scale = max(float(np.max(np.abs(b))) for b in want)
+    _check(scale > 0.0, "grad parity: the reference grad is all zero")
+    bound = min(atol, rtol * scale)
+    worst = 0.0
+    for a, b in zip(jax.tree.leaves(got), want):
+        a = np.asarray(a)
+        np.testing.assert_allclose(a, b, rtol=rtol, atol=bound)
+        worst = max(worst, float(np.max(np.abs(a - b))))
+    return worst, scale, bound
 
 
 # ---------------------------------------------------------------------------
@@ -151,15 +177,11 @@ def train_phases(cfg) -> None:
     loss_rel = _rel(lf, lu)
     _check(np.isfinite(lf) and lf != 0.0 and loss_rel <= FUSED_LOSS_RTOL,
            f"parity: loss fused {lf} vs unfused {lu} (rel {loss_rel:.2e})")
-    worst = 0.0
-    for a, b in zip(jax.tree.leaves(gf), jax.tree.leaves(gu)):
-        np.testing.assert_allclose(a, b, rtol=FUSED_GRAD_RTOL,
-                                   atol=FUSED_GRAD_ATOL)
-        worst = max(worst, float(np.max(np.abs(a - b))))
+    worst, scale, bound = _grads_close(gf, gu, FUSED_GRAD_RTOL, FUSED_GRAD_ATOL)
     log(f"parity: fused loss {lf:.9g} vs unfused {lu:.9g} (rel "
         f"{loss_rel:.2e} <= {FUSED_LOSS_RTOL:g}); tower grad max |diff| "
-        f"{worst:.2e} (rtol {FUSED_GRAD_RTOL:g}, atol {FUSED_GRAD_ATOL:g}); "
-        f"matmul precision highest")
+        f"{worst:.2e}, max |grad| {scale:.2e} (rtol {FUSED_GRAD_RTOL:g}, "
+        f"atol {bound:.2e}); matmul precision highest")
 
 
 # ---------------------------------------------------------------------------
@@ -168,6 +190,7 @@ def train_phases(cfg) -> None:
 
 def serve_phase(scale: str = "full") -> None:
     """(c) for the sasrec config of ``scale`` (full: the published one)."""
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -207,9 +230,26 @@ def serve_phase(scale: str = "full") -> None:
     _check(not route.degraded, "serve: plan degraded")
     by_rid = {r.rid: r.result for r in records}
     exact = []
-    for lo in range(0, args.requests, args.max_batch):
-        top = planner.query_exact(jnp.asarray(np.stack(payloads[lo:lo + args.max_batch])))
-        exact.extend(np.asarray(top.indices))
+    batches = [jnp.asarray(np.stack(payloads[lo:lo + args.max_batch]))
+               for lo in range(0, args.requests, args.max_batch)]
+    for x in batches:
+        exact.extend(np.asarray(planner.query_exact(x).indices))
+    # the kernel route against the jnp IVF reference on the same index
+    # and n_probe: at "highest" both score in f32, so only a fault in the
+    # kernel's probe, scoring or merge can change the ids
+    worst = 0.0
+    with jax.default_matmul_precision("highest"):
+        for x in batches:
+            got, ref = planner.query(x), planner.query_reference(x)
+            gi, ri = np.asarray(got.indices), np.asarray(ref.indices)
+            gs, rs = np.asarray(got.scores), np.asarray(ref.scores)
+            for g, r in zip(gi, ri):
+                _check(set(g.tolist()) == set(r.tolist()),
+                       f"serve: kernel ids {g} != jnp IVF reference ids {r}")
+            worst = max(worst, float(np.max(np.abs(gs - rs)) / np.max(np.abs(rs))))
+    _check(worst <= SERVE_SCORE_RTOL,
+           f"serve: kernel scores off the jnp IVF reference by {worst:.2e} "
+           f"of the largest score")
     hits = 0
     for rid, (ids, scores) in sorted(by_rid.items()):
         _check(ids.shape == (args.k,) and np.all((ids >= 0) & (ids < cfg.item_vocab))
@@ -222,8 +262,11 @@ def serve_phase(scale: str = "full") -> None:
         f"seq_len={cfg.seq_len} blocks={cfg.num_blocks}; "
         f"{len(records)}/{args.requests} answered in {engine.batches} "
         f"batches (max_batch {args.max_batch}), degraded=False, "
-        f"interpret={planner.plan.interpret}; recall@{args.k} vs exact "
-        f"fallback plan {recall:.4f} (n_probe {planner.n_probe}); set-up "
+        f"interpret={planner.plan.interpret}; kernel ids == jnp IVF "
+        f"reference ids on {args.requests}/{args.requests} requests (score "
+        f"max |diff| / max |score| {worst:.2e} <= {SERVE_SCORE_RTOL:g}, "
+        f"matmul precision highest); recall@{args.k} vs exact fallback plan {recall:.4f} "
+        f"(n_probe {planner.n_probe}); set-up "
         f"(params + index) {setup_s:.2f} s, warm-up compile {warm_s:.2f} s, "
         f"serve {serve_s * 1e3:.1f} ms, batch latency p50 "
         f"{lat[len(lat) // 2] * 1e3:.1f} ms")
@@ -307,17 +350,14 @@ def four_chip_phase(cfg) -> None:
     rel = _rel(l4, l1)
     _check(np.isfinite(l1) and l1 != 0.0 and rel <= DIST_LOSS_RTOL,
            f"four-chip: loss mesh {l4} vs one device {l1} (rel {rel:.2e})")
-    worst = 0.0
-    for a, b in zip(jax.tree.leaves(g4), jax.tree.leaves(g1)):
-        a, b = np.asarray(a), np.asarray(b)
-        np.testing.assert_allclose(a, b, rtol=DIST_GRAD_RTOL, atol=DIST_GRAD_ATOL)
-        worst = max(worst, float(np.max(np.abs(a - b))))
+    worst, scale, bound = _grads_close(g4, g1, DIST_GRAD_RTOL, DIST_GRAD_ATOL)
     log(f"four-chip: fopo-paper fused step (in-kernel sampler, covgrad "
         f"kernels) P={cfg.num_items} L={cfg.embed_dim} B={cfg.batch_size} "
         f"S={cfg.fopo.num_samples} K={cfg.fopo.top_k} on a 2x2 "
         f"(data x model) mesh vs one device: loss {l4:.9g} vs {l1:.9g} "
         f"(rel {rel:.2e} <= {DIST_LOSS_RTOL:g}); grad max |diff| "
-        f"{worst:.2e} (rtol {DIST_GRAD_RTOL:g}, atol {DIST_GRAD_ATOL:g}); "
+        f"{worst:.2e}, max |grad| {scale:.2e} (rtol {DIST_GRAD_RTOL:g}, "
+        f"atol {bound:.2e}); "
         f"interpret={plan4.interpret}; matmul precision highest; set-up "
         f"{setup_s:.2f} s, first call (compile + run) one device "
         f"{t_single:.2f} s, mesh {t_dist:.2f} s")

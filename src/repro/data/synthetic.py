@@ -195,6 +195,10 @@ def clustered_catalog(
     return items.astype(np.float32), queries.astype(np.float32)
 
 
+# norm of the Gaussian noise around a `clustered_sessions` cluster center
+CLUSTER_NOISE = 0.3
+
+
 def clustered_sessions(
     num_items: int,
     embed_dim: int,
@@ -202,7 +206,6 @@ def clustered_sessions(
     *,
     num_clusters: int,
     num_positives: int = 8,
-    noise: float = 0.3,
     seed: int = 0,
 ) -> SessionDataset:
     """A session dataset at any catalog size, made from a seed in
@@ -213,13 +216,13 @@ def clustered_sessions(
     GB).
 
     Items are ``num_clusters`` unit-norm centers plus Gaussian noise of
-    norm ~``noise``, each cluster holding ~P / C items, so an IVF index
-    with C lists stays balanced. A user's context is one cluster's
+    norm ~`CLUSTER_NOISE`, each cluster holding ~P / C items, so an IVF
+    index with C lists stays balanced. A user's context is one cluster's
     center plus noise, and its ``num_positives`` completion targets are
     items of that cluster drawn uniformly (-1 where the cluster is
     empty): the reward has signal a policy can learn."""
     rng = np.random.default_rng(seed)
-    scale = noise / np.sqrt(embed_dim)
+    scale = CLUSTER_NOISE / np.sqrt(embed_dim)
     centers = rng.standard_normal((num_clusters, embed_dim), dtype=np.float32)
     centers /= np.linalg.norm(centers, axis=1, keepdims=True)
     assign = rng.integers(0, num_clusters, size=num_items)

@@ -8,11 +8,13 @@ widths (``CONFIG``).
     PYTHONPATH=src python -m repro.launch.train --arch fopo-paper --steps 200
     PYTHONPATH=src python -m repro.launch.train --arch fopo-paper --scale full --steps 5
 
-``--scale full`` for fopo-paper trains the paper's shapes (P=750,000,
-L=100, B=32, S=1000, K=256) on the fused main path — Pallas covgrad
-kernels, the in-kernel sampler and `ivf_topk` retrieval over a
+fopo-paper trains on the fused main path at either scale — Pallas
+covgrad kernels, the in-kernel sampler and `ivf_topk` retrieval over a
 `build_ivf` index — with data made from ``--seed`` by
 `clustered_sessions` (balanced catalog, seconds of host time).
+``--scale full`` runs the paper's shapes (P=750,000, L=100, B=32,
+S=1000, K=256); off a TPU the kernels run in interpret mode, which is
+practical only at smoke widths.
 
 The production path (256/512 chips) reuses the exact same step
 functions through launch/specs.py — the dry-run proves those lower and
@@ -32,8 +34,8 @@ from repro.configs import ARCH_IDS, get_arch
 from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import adam
 
-# users in the seeded published-width dataset (contexts of the batches)
-FULL_NUM_USERS = 2048
+# users in the seeded fopo-paper dataset (contexts of the batches)
+NUM_USERS = 2048
 
 
 def arch_config(mod, scale: str):
@@ -111,14 +113,12 @@ def _train_recsys(cfg, steps: int) -> None:
         print(f"step {i}: loss={float(loss):.5f} [{objective}]")
 
 
-def fopo_paper_trainer(
-    cfg, *, seed: int = 0, fused: bool = True, fused_sampler: bool = True
-):
-    """The published-width fopo-paper trainer on the fused main path:
-    `clustered_sessions` data from ``seed``, a `build_ivf` index over
-    the catalog, and ``cfg.fopo`` with retriever="ivf_pallas" plus the
-    fused covgrad kernels / in-kernel sampler. Returns (trainer,
-    train_ds, test_ds)."""
+def fopo_paper_trainer(cfg, *, seed: int = 0):
+    """The fopo-paper trainer on the fused main path: `clustered_sessions`
+    data from ``seed``, a `build_ivf` index over the catalog, and
+    ``cfg.fopo`` with retriever="ivf_pallas" plus the fused covgrad
+    kernels and the in-kernel sampler. Returns (trainer, train_ds,
+    test_ds)."""
     from repro.data import clustered_sessions
     from repro.mips.ivf import build_ivf
     from repro.train import FOPOTrainer, TrainerConfig
@@ -128,7 +128,7 @@ def fopo_paper_trainer(
     # 64 targets per session: at eps=0.8 the uniform arm alone lands ~0.07
     # of them per row, so every batch of 32 carries reward
     data = clustered_sessions(
-        cfg.num_items, cfg.embed_dim, FULL_NUM_USERS,
+        cfg.num_items, cfg.embed_dim, NUM_USERS,
         num_clusters=num_clusters, num_positives=64, seed=seed,
     )
     train_ds, test_ds = data.split(0.9, seed=seed)
@@ -137,8 +137,7 @@ def fopo_paper_trainer(
         num_clusters=num_clusters, cap_tile=256,
     )
     fopo = dataclasses.replace(
-        cfg.fopo, retriever="ivf_pallas", fused=fused,
-        fused_sampler=fused_sampler,
+        cfg.fopo, retriever="ivf_pallas", fused=True, fused_sampler=True
     )
     trainer = FOPOTrainer(
         TrainerConfig(
@@ -151,24 +150,8 @@ def fopo_paper_trainer(
     return trainer, train_ds, test_ds
 
 
-def _train_fopo_paper(cfg, steps: int, scale: str, seed: int) -> None:
-    from repro.data import SyntheticConfig, generate_sessions
-    from repro.train import FOPOTrainer, TrainerConfig
-
-    if scale == "full":
-        tr, _, test_ds = fopo_paper_trainer(cfg, seed=seed)
-    else:
-        data = generate_sessions(
-            SyntheticConfig(num_items=cfg.num_items, num_users=2000,
-                            embed_dim=cfg.embed_dim, session_len=16,
-                            seed=seed)
-        )
-        train_ds, test_ds = data.split(0.9)
-        tr = FOPOTrainer(
-            TrainerConfig(estimator="fopo", fopo=cfg.fopo, batch_size=32,
-                          learning_rate=3e-3, num_steps=steps),
-            train_ds,
-        )
+def _train_fopo_paper(cfg, steps: int, seed: int) -> None:
+    tr, _, test_ds = fopo_paper_trainer(cfg, seed=seed)
     print(f"R_test before: {tr.evaluate(test_ds):.4f}")
     tr.train(steps, log_every=max(1, steps // 5))
     print(f"R_test after:  {tr.evaluate(test_ds):.4f}")
@@ -198,7 +181,7 @@ def main() -> None:
     elif mod.FAMILY == "recsys":
         _train_recsys(cfg, args.steps)
     else:
-        _train_fopo_paper(cfg, args.steps, args.scale, args.seed)
+        _train_fopo_paper(cfg, args.steps, args.seed)
 
 
 if __name__ == "__main__":

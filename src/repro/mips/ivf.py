@@ -252,11 +252,13 @@ def build_ivf(
     the build is fully traceable — no `.item()` / `int(jnp.max(...))`
     round-trips stalling the device queue, and the whole build jits.
     The price is that the safety rails needing concrete counts are off
-    on that path: a cluster overflowing the trusted ``cap`` silently
-    drops its overflow items (rank-clamped scatter) instead of clamping
-    cap up with a warning, and the degenerate-clustering warning is
-    skipped. Leave ``cap=None`` (the derive-from-data default) to keep
-    the eager warn-and-clamp behaviour.
+    on that path: a cluster overflowing the trusted ``cap`` drops its
+    overflow items (rank-clamped scatter) instead of clamping cap up
+    with a warning — `repro.mips.refresh.init_refresh_state` counts the
+    dropped items in `overflow`, where the health ladder sees them —
+    and the degenerate-clustering warning is skipped. Leave ``cap=None``
+    (the derive-from-data default) to keep the eager warn-and-clamp
+    behaviour.
     """
     p, l = items.shape
     if num_clusters is None:

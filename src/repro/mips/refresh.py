@@ -153,7 +153,12 @@ def init_refresh_state(
     `rows` sizes the `slot_of` position map — the id space this state
     may ever see (catalog size; per-shard slab for the sharded route).
     `id_base` shifts GLOBAL list ids into that local [0, rows) range
-    (the sharded layout bakes each slab's offset into its ids)."""
+    (the sharded layout bakes each slab's offset into its ids).
+
+    `overflow` starts at the number of catalog items in this id range
+    that the lists do not hold: a fixed-cap `build_ivf` drops a
+    cluster's rank overflow, and the health ladder's overflow watch
+    must see that loss from the first observation."""
     c, cap = index.lists.shape
     l = index.centroids.shape[1]
     flat = _flat_main(
@@ -169,6 +174,7 @@ def init_refresh_state(
         flat.reshape(-1).astype(jnp.int32), mode="drop"
     )
     occupancy = jnp.sum((index.lists >= 0).astype(jnp.float32), axis=1)
+    n_valid = jnp.clip(index.num_items - id_base, 0, rows)
     return RefreshState(
         centroids=index.centroids,
         counts=occupancy,  # seed EMA weights from the build's occupancy
@@ -178,7 +184,7 @@ def init_refresh_state(
         delta_embs=jnp.zeros((c, delta_cap, l), index.list_embs.dtype),
         delta_sizes=jnp.zeros((c,), jnp.int32),
         slot_of=slot_of,
-        overflow=jnp.zeros((), jnp.int32),
+        overflow=(n_valid - jnp.sum(index.lists >= 0)).astype(jnp.int32),
     )
 
 

@@ -96,6 +96,12 @@ class QueryPlanner:
             "compact": jax.jit(refresh_mod.compact),
             "rebuild": jax.jit(partial(refresh_mod.rebuild, iters=rebuild_iters)),
         }
+        k, n_probe = self.plan.cfg.top_k, self.n_probe
+        self._reference = jax.jit(
+            lambda params, x, state: refresh_mod.refresh_query(
+                state, policy.user_embedding(params, x), k, n_probe
+            )
+        )
         self._embed = jax.jit(policy.user_embedding)
         self._probe_h = None if probe_x is None else self._embed(params, probe_x)
 
@@ -117,6 +123,14 @@ class QueryPlanner:
         plan, whatever the live plan is — the reference a recall check
         holds the live index to (same jitted trace `degrade()` swaps in)."""
         return self._fallback(self.params, x, self.beta, self.index_state)
+
+    def query_reference(self, x: jnp.ndarray):
+        """(x [B, Dx]) -> TopK through the pure-jnp IVF query over the
+        live index (`refresh_query`: main lists and delta buffers, the
+        same n_probe) — the reference the `ivf_topk` kernel route is
+        held to: the same candidates, scored and merged without the
+        kernel, so its ids must equal the primary plan's."""
+        return self._reference(self.params, x, self.index_state)
 
     def warmup(self, x_example: jnp.ndarray) -> None:
         """Compile the primary AND fallback query paths before traffic:

@@ -90,17 +90,17 @@ def make_trainer(ds, health=None, fault=None, *, steps=6, seed=0,
 
 def make_refresh_trainer(ds, health=None, fault=None, *, steps=6,
                          ckpt_dir=None, ckpt_every=0, every=2,
-                         compact_every=0):
+                         compact_every=0, cap=None):
     from repro.mips.ivf import build_ivf
 
     items = jnp.asarray(ds.item_embeddings)
     # cap=None sizes the lists to the largest cluster, so the index holds
     # every item: this catalog is lopsided (one k-means cluster takes
     # most of the 300 items), and a fixed cap below that cluster's size
-    # would silently drop its overflow from the lists
+    # drops its overflow from the lists (counted as index overflow)
     index = build_ivf(
-        jax.random.PRNGKey(1), items, num_clusters=8, kmeans_iters=3,
-        cap_tile=32,
+        jax.random.PRNGKey(1), items, num_clusters=8, cap=cap,
+        kmeans_iters=3, cap_tile=32,
     )
     fopo = FOPOConfig(
         num_items=300, num_samples=32, top_k=16, epsilon=0.8,
@@ -615,6 +615,25 @@ def test_monitor_overflow_delta_trigger():
     m.note_compaction(0)
     assert m.last_overflow == 0
     assert m.observe(None, 5) is None  # re-based after compaction
+
+
+def test_fixed_cap_build_drop_is_overflow_the_ladder_sees(ds):
+    """A fixed-cap build that cannot hold the largest cluster drops its
+    overflow from the lists; the maintained state counts the missing
+    items as overflow from the start, so the ladder's overflow watch
+    escalates on its first observation. A build that holds every item
+    starts with no overflow."""
+    assert int(make_refresh_trainer(ds).index_state.overflow) == 0
+    ih = IndexHealthConfig(overflow_budget=8, cooldown=0)
+    t = make_refresh_trainer(ds, health=HealthConfig(index=ih), steps=1,
+                             every=0, cap=128)
+    lists = np.asarray(t.index_state.lists)
+    held = int(np.sum(lists >= 0))
+    assert held < 300
+    assert int(t.index_state.overflow) == 300 - held
+    probes = t.train(1)["index_health"]
+    assert probes[0]["overflow"] == 300 - held
+    assert probes[0]["action"] == "compact"
 
 
 def test_corrupt_index_recall_collapses_and_compact_heals(ds):

@@ -60,3 +60,33 @@ def test_clustered_sessions_is_balanced_seeded_and_rewarding():
     # one cluster center) leaves no bucket far above P / C items
     nearest = np.argmax(ds.item_embeddings @ centers.T, axis=1)
     assert np.max(np.bincount(nearest)) < 3 * p / c
+
+
+def test_fopo_paper_trainer_runs_the_fused_path_at_any_width():
+    """Both launcher scales train through one path: fused covgrad
+    kernels, the in-kernel sampler and ivf_pallas retrieval (interpret
+    mode off a TPU), here at a toy width."""
+    import dataclasses
+
+    import jax
+
+    from repro.configs import get_arch
+    from repro.core.fopo import FOPOConfig
+    from repro.launch.train import fopo_paper_trainer
+
+    cfg = dataclasses.replace(
+        get_arch("fopo-paper").SMOKE_CONFIG, num_items=512, embed_dim=8,
+        batch_size=4,
+        fopo=FOPOConfig(num_items=512, num_samples=16, top_k=8, epsilon=0.8),
+    )
+    trainer, train_ds, test_ds = fopo_paper_trainer(cfg, seed=1)
+    fc = trainer.plan.cfg
+    assert fc.fused and fc.fused_sampler and fc.retriever == "ivf_pallas"
+    assert trainer.cfg.batch_size == 4 and trainer.beta.shape == (512, 8)
+    before = jax.tree.map(np.asarray, trainer.params)
+    losses = trainer.train(2)["loss"]
+    assert np.all(np.isfinite(losses))
+    moved = [np.max(np.abs(np.asarray(a) - b)) for a, b in
+             zip(jax.tree.leaves(trainer.params), jax.tree.leaves(before))]
+    assert max(moved) > 0.0
+    assert np.isfinite(trainer.evaluate(test_ds))

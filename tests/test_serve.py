@@ -151,6 +151,18 @@ def test_query_exact_is_exact_top_k_of_the_user_tower():
     assert not planner.degraded and planner.fallback_plan.degraded
 
 
+def test_query_reference_matches_the_kernel_route():
+    """The planner's jnp IVF reference probes the same lists as the
+    `ivf_topk` kernel route at the same n_probe: same ids, same scores."""
+    cfg, params = _sasrec()
+    planner = RecsysMIPSRoute(cfg, params, k=8).planner
+    x = jax.numpy.asarray(np.stack(_hists(cfg, 4)))
+    got, want = planner.query(x), planner.query_reference(x)
+    np.testing.assert_array_equal(np.asarray(got.indices), np.asarray(want.indices))
+    np.testing.assert_allclose(np.asarray(got.scores), np.asarray(want.scores),
+                               rtol=1e-5, atol=1e-6)
+
+
 def test_submit_rejects_decreasing_arrivals():
     cfg, params = _sasrec()
     eng = ServingEngine(RecsysMIPSRoute(cfg, params, k=4))

@@ -468,17 +468,14 @@ class ExecutionPlan:
         maintained index rides as ``index_state`` exactly as in
         `execute()`, which is what lets the serving engine reuse the
         degradation ladder unchanged."""
-        h = self._user_embedding(policy, params, x, route="serve")
+        h = self._user_embedding(policy, params, x)
         return self.retrieve(h, beta, index_state)
 
-    def _user_embedding(self, policy, params, x, route="train") -> jnp.ndarray:
+    def _user_embedding(self, policy, params, x) -> jnp.ndarray:
         """h_theta(x) under stop_gradient — shared by `execute()` and
         `execute_query()` so the training and serving paths embed
         identically by construction."""
-        from repro.obs.trace import span
-
-        with span("user_embedding", route=route):
-            return jax.lax.stop_gradient(policy.user_embedding(params, x))
+        return jax.lax.stop_gradient(policy.user_embedding(params, x))
 
     # ------------------------------------------------------------------
     # the shared step skeleton: retrieval -> sample -> weight -> reduce
@@ -499,26 +496,17 @@ class ExecutionPlan:
         and surrogate fire. Returns (loss, aux). Under a refresh plan
         ``index_state`` is the maintained index (defaults to the plan's
         initial state) — pass the trainer's current state so retrieval
-        sees appended/refreshed items.
-
-        The repro.obs spans below run at TRACE time (execute is jitted):
-        each fires once per compile and measures tracing that segment —
-        the breakdown that localises a retrace, not per-step runtime
-        (per-step phases are the trainer's dispatch/drain spans)."""
-        from repro.obs.trace import span
-
+        sees appended/refreshed items."""
         eps = self.cfg.epsilon if epsilon is None else epsilon
         h_prop = self._user_embedding(policy, params, x)
         sample = self.draw(key, h_prop, beta, eps, index_state=index_state)
         # clamp keeps reward lookups in-bounds on pre-masked (padded)
         # slots; their reward is zeroed and their SNIS weight is 0
         valid = sample.actions >= 0
-        with span("reward"):
-            rewards = jax.lax.stop_gradient(
-                reward_fn(jnp.maximum(sample.actions, 0)) * valid
-            )
-        with span("surrogate"):
-            return self.surrogate(policy, params, x, beta, sample, rewards)
+        rewards = jax.lax.stop_gradient(
+            reward_fn(jnp.maximum(sample.actions, 0)) * valid
+        )
+        return self.surrogate(policy, params, x, beta, sample, rewards)
 
     # -- retrieval ------------------------------------------------------
     def retrieve(
@@ -527,23 +515,20 @@ class ExecutionPlan:
         beta: jnp.ndarray,
         index_state: "RefreshState | None" = None,
     ) -> "TopK":
-        from repro.obs.trace import span
-
-        with span("retrieval", route=self.cfg.retriever):
-            if self.initial_index_state is not None:
-                state = (
-                    index_state if index_state is not None
-                    else self.initial_index_state
-                )
-                return self.retriever(h_prop, beta, state)
-            if self.retriever is not None:
-                return self.retriever(h_prop, beta)
-            from repro.dist.fopo import dist_sharded_topk
-
-            return dist_sharded_topk(
-                h_prop, beta, self.cfg.top_k, self.dist,
-                num_items=self.cfg.num_items,
+        if self.initial_index_state is not None:
+            state = (
+                index_state if index_state is not None
+                else self.initial_index_state
             )
+            return self.retriever(h_prop, beta, state)
+        if self.retriever is not None:
+            return self.retriever(h_prop, beta)
+        from repro.dist.fopo import dist_sharded_topk
+
+        return dist_sharded_topk(
+            h_prop, beta, self.cfg.top_k, self.dist,
+            num_items=self.cfg.num_items,
+        )
 
     # -- sampling -------------------------------------------------------
     def draw(self, key, h_prop, beta, eps, index_state=None) -> "ProposalSample":
@@ -551,14 +536,10 @@ class ExecutionPlan:
         number) eps >= 1 short-circuits retrieval entirely (pure
         uniform proposal); a traced eps takes the mixture route, which
         reproduces the uniform pmf exactly at eps == 1."""
-        from repro.obs.trace import span
-
         if isinstance(eps, (int, float)) and eps >= 1.0:
-            with span("sample", route="uniform"):
-                return self._draw_uniform(key, h_prop.shape[0])
+            return self._draw_uniform(key, h_prop.shape[0])
         topk = self.retrieve(h_prop, beta, index_state)
-        with span("sample", route="fused" if self.fused_sampler else "mixture"):
-            return self._draw_mixture(key, topk, eps)
+        return self._draw_mixture(key, topk, eps)
 
     def _draw_uniform(self, key, batch: int) -> "ProposalSample":
         from repro.core.proposals import UniformProposal

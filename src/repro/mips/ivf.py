@@ -35,6 +35,7 @@ import jax.numpy as jnp
 
 from repro.mips.exact import TopK, merge_topk
 from repro.mips.streaming import NEG_INF  # noqa: F401  (re-export; kernels import it here)
+from repro.obs.trace import current, span
 
 
 DEFAULT_CAP_TILE = 256
@@ -260,6 +261,17 @@ def build_ivf(
     (the derive-from-data default) to keep the eager warn-and-clamp
     behaviour.
     """
+    with span("index_build"):
+        index = _build_ivf(key, items, num_clusters, cap, kmeans_iters, cap_tile)
+        if current() is not None and not isinstance(index.lists, jax.core.Tracer):
+            # traced runs time the build, not its enqueue; untraced
+            # callers keep an asynchronous build
+            jax.block_until_ready(index)
+    return index
+
+
+def _build_ivf(key, items, num_clusters, cap, kmeans_iters, cap_tile) -> IVFIndex:
+    """`build_ivf`'s body."""
     p, l = items.shape
     if num_clusters is None:
         num_clusters = max(1, int(2 ** round(jnp.log2(jnp.sqrt(p)).item())))
@@ -290,7 +302,7 @@ def build_ivf(
         warnings.warn(
             f"build_ivf: requested cap={cap} < largest cluster "
             f"({max_count} items); clamping cap to {max_count}",
-            stacklevel=2,
+            stacklevel=3,
         )
         cap = max_count
     if cap is None:
@@ -303,7 +315,7 @@ def build_ivf(
         warnings.warn(
             f"build_ivf: degenerate clustering — largest cluster holds "
             f"{max_count}/{p} items; queries probing it cost O(P*L)",
-            stacklevel=2,
+            stacklevel=3,
         )
     lists, list_embs = bucket_items(assign, items, num_clusters, cap)
     return IVFIndex(

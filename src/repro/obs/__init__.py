@@ -8,8 +8,8 @@ benchmark and serving code:
            labels; zero-host-sync (device scalars recorded as futures,
            drained after `block_until_ready`); pluggable sinks
            (in-memory ring, JSONL file, human log lines)
-  trace    `span("retrieval")` phase spans -> Chrome-trace JSON, plus
-           config-gated jax.profiler hooks
+  trace    `span("dispatch", step=3)` phase spans -> Chrome-trace JSON
+           and the jax.profiler trace, plus config-gated profiler hooks
   drift    `DriftMonitor` — measured step time vs the analytic roofline
            models, EMA ratio + hysteresis warnings (the autotuner's
            feedback signal)

@@ -45,7 +45,8 @@ class ObsConfig:
     jsonl           write the JSONL record stream (needs run_dir)
     trace           record phase spans into a Chrome trace (written to
                     run_dir when set; span recording itself is
-                    in-memory and costs one list append per phase)
+                    in-memory: one list append and one profiler
+                    annotation per phase)
     jax_profiler    start a jax.profiler trace into run_dir/jaxprof —
                     device-level timelines, strictly config-gated
     drift           DriftConfig arming the roofline-drift monitor
@@ -100,11 +101,14 @@ class ObsRun:
         if cfg is not None and cfg.drift is not None and predicted_step_s:
             self.drift = DriftMonitor(predicted_step_s, cfg.drift)
         self._profiling = False
+        self._tracing = None
 
     # -- lifecycle ------------------------------------------------------
     def __enter__(self) -> "ObsRun":
         if self.tracer is not None:
-            trace_mod.activate(self.tracer)
+            # restores, on exit, whatever tracer was installed before
+            self._tracing = trace_mod.tracing(self.tracer)
+            self._tracing.__enter__()
         if self.cfg is not None and self.cfg.jax_profiler and self.run_dir:
             self._profiling = trace_mod.start_jax_profiler(
                 os.path.join(self.run_dir, "jaxprof")
@@ -112,8 +116,9 @@ class ObsRun:
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        if self.tracer is not None:
-            trace_mod.deactivate()
+        if self._tracing is not None:
+            self._tracing.__exit__(None, None, None)
+            self._tracing = None
             if self.run_dir:
                 self.tracer.write(os.path.join(self.run_dir, TRACE_FILE))
         if self._profiling:

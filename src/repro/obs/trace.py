@@ -1,23 +1,33 @@
-"""Phase tracing: a span API emitting Chrome-trace-format JSON.
+"""Phase tracing: named host spans, kept as Chrome-trace JSON and
+written into the `jax.profiler` trace.
 
-``span("retrieval")`` wraps a host-side phase (trainer dispatch/drain,
-index refresh/compact, checkpoint save/restore, health probes) or a
-trace-time phase of the step skeleton (`ExecutionPlan.execute` runs
-under jit — its spans measure *tracing* that segment, recorded once per
-compile, which is exactly the breakdown you want when a retrace
-sneaks in). Spans are nested naturally via ts/dur on one thread track;
-load the written ``trace.json`` in chrome://tracing or Perfetto.
+``span("dispatch", step=3)`` wraps a host-side phase at a layer
+boundary: the trainer's step and its children (`next_batch`,
+`dispatch`, `index_refresh`, `drain`, `record`), the serving engine's
+batch (`serve_prepare`, `serve_run`, `serve_wait`, `serve_finalize`,
+`serve_record`), the IVF build (`index_build`), index maintenance,
+checkpoint save/restore and health probes. Spans nest by ts/dur on one
+thread track; ``args`` carry the step or batch id.
 
-The tracer is ambient: `activate()`/`deactivate()` (or the `tracing()`
-context manager) install one, and `span()` is a cheap no-op when none
-is installed — so library code (the plan, checkpointing, serve) can
-wrap phases unconditionally without plumbing a tracer operand through
-every signature.
+Each span is recorded twice:
 
-`jax.profiler` hooks ride the same gate: `start_jax_profiler(dir)` /
-`stop_jax_profiler()` wrap the device-level profiler for runs that
-need XLA timelines, enabled by `ObsConfig(jax_profiler=True)` only —
-never ambient.
+* as a Chrome 'complete' event on the tracer, written to ``trace.json``
+  by `ObsRun(run_dir=...)` (load it in chrome://tracing or Perfetto);
+* as a `jax.profiler.TraceAnnotation` named ``repro.<name>`` with the
+  args as its stats. Inside a profiler session it lands on the host
+  plane, on the clock of the device's op events, so a device idle gap
+  can be charged to the phase the host was in. Outside one it costs the
+  annotation's construction.
+
+The tracer is ambient: the `tracing()` context manager installs one
+(restoring whatever was installed before), and `span()` is one global
+read and a no-op when none is installed, touching neither the tracer
+nor `jax.profiler` — so library code wraps phases unconditionally
+without plumbing a tracer operand through every signature.
+
+`start_jax_profiler(dir)` / `stop_jax_profiler()` wrap the device-level
+profiler for runs that need XLA timelines, enabled by
+`ObsConfig(jax_profiler=True)` only — never ambient.
 """
 from __future__ import annotations
 
@@ -26,16 +36,18 @@ import os
 import time
 from contextlib import contextmanager
 
+from jax import profiler
+
 __all__ = [
     "Tracer",
-    "activate",
     "current",
-    "deactivate",
     "span",
     "start_jax_profiler",
     "stop_jax_profiler",
     "tracing",
 ]
+
+PROFILER_PREFIX = "repro."
 
 _ACTIVE: "Tracer | None" = None
 
@@ -55,20 +67,14 @@ class Tracer:
     def span(self, name: str, **args):
         ts = self._now_us()
         try:
-            yield
+            with profiler.TraceAnnotation(PROFILER_PREFIX + name, **args):
+                yield
         finally:
             ev = {"name": name, "ph": "X", "ts": ts,
                   "dur": self._now_us() - ts, "pid": 0, "tid": 0}
             if args:
                 ev["args"] = args
             self.events.append(ev)
-
-    def instant(self, name: str, **args) -> None:
-        ev = {"name": name, "ph": "i", "ts": self._now_us(), "pid": 0,
-              "tid": 0, "s": "t"}
-        if args:
-            ev["args"] = args
-        self.events.append(ev)
 
     def write(self, path: str) -> str:
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
@@ -80,16 +86,6 @@ class Tracer:
 # ---------------------------------------------------------------------------
 # the ambient tracer
 # ---------------------------------------------------------------------------
-
-def activate(tracer: Tracer) -> None:
-    global _ACTIVE
-    _ACTIVE = tracer
-
-
-def deactivate() -> None:
-    global _ACTIVE
-    _ACTIVE = None
-
 
 def current() -> Tracer | None:
     return _ACTIVE
@@ -126,20 +122,16 @@ def span(name: str, **args):
 def start_jax_profiler(log_dir: str) -> bool:
     """Start a jax.profiler trace into ``log_dir``. Returns False (and
     stays off) when the backend/profiler is unavailable."""
-    import jax
-
     try:
         os.makedirs(log_dir, exist_ok=True)
-        jax.profiler.start_trace(log_dir)
+        profiler.start_trace(log_dir)
         return True
     except Exception:
         return False
 
 
 def stop_jax_profiler() -> None:
-    import jax
-
     try:
-        jax.profiler.stop_trace()
+        profiler.stop_trace()
     except Exception:
         pass

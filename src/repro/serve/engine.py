@@ -191,20 +191,27 @@ class ServingEngine:
         (the replica never did the work)."""
         if not batch:
             return DrainResult()
+        b = self.batches
+        with span("serve_batch", batch=b, n=len(batch)):
+            return self._serve(batch, not_before, b)
+
+    def _serve(self, batch: list[Request], not_before: float, b: int) -> DrainResult:
+        """`serve_batch`'s body, one child span a phase (``batch=b``)."""
         size = len(batch)
         launch = max(self.free_at, not_before, max(r.arrival for r in batch))
         try:
-            payloads = pad_payloads(
-                [r.payload for r in batch], self.policy.max_batch,
-                self.route.pad_payload,
-            )
-            with span("serve_batch", batch=self.batches, n=size):
-                with span("serve_prepare", batch=self.batches):
-                    prepared = self.route.prepare(payloads)
-                t0 = time.perf_counter()
-                with span("serve_run", batch=self.batches):
-                    out = jax.block_until_ready(self.route.run(prepared))
-                measured = time.perf_counter() - t0
+            with span("serve_prepare", batch=b):
+                payloads = pad_payloads(
+                    [r.payload for r in batch], self.policy.max_batch,
+                    self.route.pad_payload,
+                )
+                prepared = self.route.prepare(payloads)
+            t0 = time.perf_counter()
+            with span("serve_run", batch=b):
+                out = self.route.run(prepared)
+            with span("serve_wait", batch=b):
+                out = jax.block_until_ready(out)
+            measured = time.perf_counter() - t0
         except ReplicaFailure as exc:
             self.bus.counter("serve_abandoned", size, **self.labels)
             self.bus.drain()
@@ -212,39 +219,37 @@ class ServingEngine:
         service = (
             measured
             if self.service_model is None
-            else float(self.service_model(measured, self.batches))
+            else float(self.service_model(measured, b))
         )
         finish = launch + service
         self.free_at = finish
-        results = self.route.finalize(out, size)
-        recs = []
-        for req, result in zip(batch, results):
-            rec = RequestRecord(
-                rid=req.rid, arrival=req.arrival, launch=launch,
-                finish=finish, batch_size=size, result=result,
+        with span("serve_finalize", batch=b):
+            results = self.route.finalize(out, size)
+        with span("serve_record", batch=b):
+            recs = []
+            for req, result in zip(batch, results):
+                rec = RequestRecord(
+                    rid=req.rid, arrival=req.arrival, launch=launch,
+                    finish=finish, batch_size=size, result=result,
+                )
+                recs.append(rec)
+                self.records.append(rec)
+                self.bus.timing(
+                    "serve_queue_wait", rec.queue_wait, step=req.rid, **self.labels
+                )
+                self.bus.timing(
+                    "serve_latency", rec.latency, step=req.rid, **self.labels
+                )
+            self.bus.timing("serve_batch_service", service, step=b, **self.labels)
+            self.bus.gauge("serve_batch_size", float(size), step=b, **self.labels)
+            self.bus.gauge(
+                "serve_occupancy", size / self.policy.max_batch, step=b,
+                **self.labels,
             )
-            recs.append(rec)
-            self.records.append(rec)
-            self.bus.timing(
-                "serve_queue_wait", rec.queue_wait, step=req.rid, **self.labels
-            )
-            self.bus.timing(
-                "serve_latency", rec.latency, step=req.rid, **self.labels
-            )
-        self.bus.timing(
-            "serve_batch_service", service, step=self.batches, **self.labels
-        )
-        self.bus.gauge(
-            "serve_batch_size", float(size), step=self.batches, **self.labels
-        )
-        self.bus.gauge(
-            "serve_occupancy", size / self.policy.max_batch,
-            step=self.batches, **self.labels,
-        )
-        self.bus.counter("serve_requests", size, **self.labels)
-        self.batches += 1
-        self._maybe_probe()
-        self.bus.drain()
+            self.bus.counter("serve_requests", size, **self.labels)
+            self.batches += 1
+            self._maybe_probe()
+            self.bus.drain()
         return DrainResult(recs)
 
     # -- the degradation ladder ----------------------------------------

@@ -502,6 +502,18 @@ class FOPOTrainer:
 
     # ------------------------------------------------------------------
     def train(self, num_steps: int | None = None, log_every: int = 0) -> dict:
+        """Run ``num_steps`` steps; returns the validated history.
+
+        Spans (`repro.obs.trace`, with ``step=`` args): ``train`` around
+        the call, one ``train_step`` a step with children ``next_batch``
+        (loader, key split, eps, fault signals), ``dispatch`` (placement
+        and the jitted step's enqueue), ``index_refresh`` (when armed),
+        ``drain`` (the wait for the loss) and ``record`` (the bus, step
+        time, verdict, snapshot, probe, checkpoint, eval, log line)."""
+        with span("train", step=self.step):
+            return self._train(num_steps, log_every)
+
+    def _train(self, num_steps: int | None, log_every: int) -> dict:
         cfg = self.cfg
         health = cfg.health
         n = num_steps if num_steps is not None else cfg.num_steps
@@ -512,107 +524,115 @@ class FOPOTrainer:
         # history backing (cfg.obs=None still runs bus + ring + human
         # log sink — no files, no tracer, no drift monitor)
         with ObsRun(cfg.obs, predicted_step_s=self._predicted_step_s()) as run:
-            bus = run.bus
             if self._monitor is not None:
-                self._monitor.bind_bus(bus)
-            i = 0
-            while i < n:
-                i += 1
-                if self.fault_plan is not None:
-                    self.fault_plan.maybe_kill(self.step)
-                batch = self.loader.next_batch()
-                self._train_key, sub = jax.random.split(self._train_key)
-                eps = adaptive_epsilon(self.step, cfg.num_steps) if cfg.adaptive_eps else 0.0
-                fault = (
-                    self.fault_plan.signals(self.step)
-                    if self.fault_plan is not None else None
-                )
-                t0 = time.perf_counter()
-                with span("dispatch", step=self.step):
-                    (
-                        self.params, self.opt_state, self.guard_state, loss,
-                        aux, verdict,
-                    ) = self._train_step(
-                        self.params,
-                        self.opt_state,
-                        self.guard_state,
-                        sub,
-                        self._place_batch(batch["contexts"]),
-                        self._place_batch(batch["positives"]),
-                        eps,
-                        self.beta,
-                        self.index_state,
-                        fault,
-                    )
-                # device scalars go on the bus NOW, as in-flight futures —
-                # they are only read at drain(), after the block below
-                bus.gauge("loss", loss, step=self.step)
-                for k in DIAGNOSTIC_KEYS:
-                    if k in aux:
-                        bus.gauge(k, aux[k], step=self.step)
-                if self._refresh_fns is not None:
-                    # dispatched async while the step above is in flight —
-                    # the step never blocks on maintenance (and vice versa)
-                    with span("index_refresh", step=self.step):
-                        self._maybe_refresh_index()
-                with span("drain", step=self.step):
-                    jax.block_until_ready(loss)
-                run.observe_step_time(time.perf_counter() - t0, self.step)
-                self.step += 1
-                # the verdict is consumed HERE, after the step result is
-                # already on host — reading it adds no step-time sync
-                v = int(verdict) if health is not None else 0
-                if v:
-                    from repro.health.guard import verdict_record
-
-                    bus.event("health", verdict_record(self.step, v),
-                              step=self.step)
-                    if int(self.guard_state.consecutive_bad) >= health.max_consecutive_bad:
-                        rolled_to = (
-                            self._snapshot["step"] if self._snapshot else self.step
-                        )
-                        self._rollback()
-                        bus.event(
-                            "events",
-                            {"step": self.step, "event": "rollback",
-                             "to": rolled_to, "restarts": self._restarts},
-                            step=self.step,
-                        )
-                        if log_every:
-                            bus.log(format_rollback_line(
-                                self.step, rolled_to, self._restarts
-                            ))
-                        bus.drain()
-                        continue
-                elif (
-                    health is not None
-                    and self.step % health.snapshot_every == 0
-                ):
-                    self._take_snapshot()
-                with span("index_probe", step=self.step):
-                    self._maybe_probe_index(bus)
-                if cfg.checkpoint_every and self.step % cfg.checkpoint_every == 0:
-                    self.save()
-                if cfg.eval_every and self.step % cfg.eval_every == 0:
-                    with span("eval", step=self.step):
-                        bus.event(
-                            "reward",
-                            {"step": self.step, "value": self.evaluate()},
-                            step=self.step,
-                        )
-                if log_every and self.step % log_every == 0:
-                    from repro.health.guard import decode_verdict
-
-                    bus.log(format_train_line(
-                        self.step, float(loss),
-                        {k: float(aux[k]) for k in DIAGNOSTIC_KEYS if k in aux},
-                        decode_verdict(v) if v else (),
-                        self._degraded,
-                    ))
-                bus.drain()  # post-block: futures -> host floats, logs out
+                self._monitor.bind_bus(run.bus)
+            for _ in range(n):
+                with span("train_step", step=self.step):
+                    self._step(run, log_every)
             history = run.history()
         history["total_time"] = time.perf_counter() - t_total
         return validate_history(history)
+
+    def _step(self, run: ObsRun, log_every: int) -> None:
+        """One step of `train` (see its docstring for the spans)."""
+        cfg = self.cfg
+        step = self.step
+        with span("next_batch", step=step):
+            if self.fault_plan is not None:
+                self.fault_plan.maybe_kill(step)
+            batch = self.loader.next_batch()
+            self._train_key, sub = jax.random.split(self._train_key)
+            eps = adaptive_epsilon(step, cfg.num_steps) if cfg.adaptive_eps else 0.0
+            fault = (
+                self.fault_plan.signals(step)
+                if self.fault_plan is not None else None
+            )
+        t0 = time.perf_counter()
+        with span("dispatch", step=step):
+            (
+                self.params, self.opt_state, self.guard_state, loss,
+                aux, verdict,
+            ) = self._train_step(
+                self.params,
+                self.opt_state,
+                self.guard_state,
+                sub,
+                self._place_batch(batch["contexts"]),
+                self._place_batch(batch["positives"]),
+                eps,
+                self.beta,
+                self.index_state,
+                fault,
+            )
+        if self._refresh_fns is not None:
+            # dispatched async while the step above is in flight —
+            # the step never blocks on maintenance (and vice versa)
+            with span("index_refresh", step=step):
+                self._maybe_refresh_index()
+        with span("drain", step=step):
+            jax.block_until_ready(loss)
+        step_time = time.perf_counter() - t0
+        with span("record", step=step):
+            self._record(run, log_every, step_time, loss, aux, verdict)
+
+    def _record(self, run: ObsRun, log_every: int, step_time: float, loss, aux,
+                verdict) -> None:
+        """The host side of a finished step: the bus, the guard's
+        verdict, snapshot or rollback, probe, checkpoint, eval, log."""
+        cfg = self.cfg
+        health = cfg.health
+        bus = run.bus
+        bus.gauge("loss", loss, step=self.step)
+        for k in DIAGNOSTIC_KEYS:
+            if k in aux:
+                bus.gauge(k, aux[k], step=self.step)
+        run.observe_step_time(step_time, self.step)
+        self.step += 1
+        # the verdict is consumed HERE, after the step result is
+        # already on host — reading it adds no step-time sync
+        v = int(verdict) if health is not None else 0
+        if v:
+            from repro.health.guard import verdict_record
+
+            bus.event("health", verdict_record(self.step, v), step=self.step)
+            if int(self.guard_state.consecutive_bad) >= health.max_consecutive_bad:
+                rolled_to = self._snapshot["step"] if self._snapshot else self.step
+                self._rollback()
+                bus.event(
+                    "events",
+                    {"step": self.step, "event": "rollback",
+                     "to": rolled_to, "restarts": self._restarts},
+                    step=self.step,
+                )
+                if log_every:
+                    bus.log(format_rollback_line(
+                        self.step, rolled_to, self._restarts
+                    ))
+                bus.drain()
+                return
+        elif health is not None and self.step % health.snapshot_every == 0:
+            self._take_snapshot()
+        with span("index_probe", step=self.step):
+            self._maybe_probe_index(bus)
+        if cfg.checkpoint_every and self.step % cfg.checkpoint_every == 0:
+            self.save()
+        if cfg.eval_every and self.step % cfg.eval_every == 0:
+            with span("eval", step=self.step):
+                bus.event(
+                    "reward",
+                    {"step": self.step, "value": self.evaluate()},
+                    step=self.step,
+                )
+        if log_every and self.step % log_every == 0:
+            from repro.health.guard import decode_verdict
+
+            bus.log(format_train_line(
+                self.step, float(loss),
+                {k: float(aux[k]) for k in DIAGNOSTIC_KEYS if k in aux},
+                decode_verdict(v) if v else (),
+                self._degraded,
+            ))
+        bus.drain()  # post-block: futures -> host floats, logs out
 
     def _predicted_step_s(self) -> float | None:
         """Analytic roofline prediction of one step's wall time — the

@@ -99,6 +99,35 @@ def test_build_ivf_static_path_jits_without_host_sync():
     assert sorted(fids[fids >= 0].tolist()) == list(range(200))
 
 
+def test_build_ivf_index_build_span_waits_only_when_traced(monkeypatch):
+    """`build_ivf` records one `index_build` span; it waits for the
+    index's arrays only under an installed tracer (the span then times
+    the device work), never without one, and never while jit traces it."""
+    from repro.mips.ivf import IVFIndex
+    from repro.obs.trace import Tracer, tracing
+
+    waited = []
+    block = jax.block_until_ready
+
+    def counting_block(x):
+        if isinstance(x, IVFIndex):
+            waited.append(x)
+        return block(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", counting_block)
+    items = jax.random.normal(jax.random.PRNGKey(0), (200, 8))
+    build_ivf(jax.random.PRNGKey(1), items, num_clusters=4, kmeans_iters=2)
+    assert waited == []
+    tr = Tracer()
+    with tracing(tr):
+        index = build_ivf(jax.random.PRNGKey(1), items, num_clusters=4,
+                          kmeans_iters=2)
+        jax.jit(lambda k, it: build_ivf(k, it, num_clusters=4, cap=64,
+                                        kmeans_iters=2))(jax.random.PRNGKey(1), items)
+    assert len(waited) == 1 and waited[0] is index
+    assert [e["name"] for e in tr.events] == ["index_build", "index_build"]
+
+
 def test_build_ivf_cap_tile_alignment():
     items = jax.random.normal(jax.random.PRNGKey(0), (300, 8))
     index = build_ivf(jax.random.PRNGKey(1), items, num_clusters=8, cap_tile=48)

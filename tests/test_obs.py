@@ -301,6 +301,88 @@ def test_span_is_noop_without_tracer():
     assert trace_mod.current() is None
 
 
+def test_span_without_tracer_never_reaches_the_profiler(monkeypatch):
+    def refuse(*a, **kw):
+        raise AssertionError("span() reached jax.profiler with no tracer")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    with span("phantom", step=3):
+        pass
+
+
+def test_span_enters_a_profiler_annotation_with_its_args(monkeypatch):
+    entered = []
+
+    class Annotation:
+        def __init__(self, name, **kw):
+            self.name, self.kw = name, kw
+
+        def __enter__(self):
+            entered.append((self.name, self.kw))
+
+        def __exit__(self, *exc):
+            entered.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    tr = Tracer()
+    with tracing(tr):
+        with span("dispatch", step=7):
+            assert entered == [("repro.dispatch", {"step": 7})]
+    assert entered[-1] == ("exit", "repro.dispatch")
+    assert tr.events[0]["name"] == "dispatch"
+    assert tr.events[0]["args"] == {"step": 7}
+
+
+def test_obsrun_restores_the_tracer_it_found(tmp_path):
+    outer = Tracer()
+    with tracing(outer):
+        with ObsRun(ObsConfig(run_dir=str(tmp_path / "run"), drift=None)) as run:
+            assert trace_mod.current() is run.tracer is not outer
+            with span("inner"):
+                pass
+        assert trace_mod.current() is outer
+        with span("after"):
+            pass
+    assert [e["name"] for e in outer.events] == ["after"]
+    assert [e["name"] for e in run.tracer.events] == ["inner"]
+    assert trace_mod.current() is None
+
+
+def _children(events, parent):
+    """The events that lie inside ``parent`` by ts/dur, itself excluded."""
+    lo, hi = parent["ts"], parent["ts"] + parent["dur"]
+    return [e for e in events if e is not parent
+            and lo <= e["ts"] and e["ts"] + e["dur"] <= hi]
+
+
+def test_trainer_spans_nest_by_step(ds):
+    tr = Tracer()
+    with tracing(tr):
+        _trainer(ds).train(2)
+    ev = tr.events
+    (train,) = [e for e in ev if e["name"] == "train"]
+    steps = [e for e in ev if e["name"] == "train_step"]
+    assert [e["args"]["step"] for e in steps] == [0, 1]
+    assert len(_children(ev, train)) == len(ev) - 1
+    for st in steps:
+        inside = _children(ev, st)
+        names = {e["name"] for e in inside}
+        assert {"next_batch", "dispatch", "drain", "record"} <= names
+        assert "train_step" not in names
+        for e in inside:
+            if e["name"] in ("next_batch", "dispatch", "drain", "record"):
+                assert e["args"]["step"] == st["args"]["step"]
+    # the phases run in order, one after another
+    first = sorted(
+        (e for e in _children(ev, steps[0])
+         if e["name"] in ("next_batch", "dispatch", "drain", "record")),
+        key=lambda e: e["ts"],
+    )
+    assert [e["name"] for e in first] == ["next_batch", "dispatch", "drain", "record"]
+    for a, b in zip(first, first[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"]
+
+
 # ---------------------------------------------------------------------------
 # roofline-drift monitor
 # ---------------------------------------------------------------------------
@@ -519,8 +601,8 @@ def test_run_artifacts_and_report(ds, tmp_path):
 
     doc = json.load(open(os.path.join(run_dir, "trace.json")))
     names = {e["name"] for e in doc["traceEvents"]}
-    # host phases per step + trace-time skeleton phases (one per compile)
-    assert {"dispatch", "drain", "retrieval", "sample", "surrogate"} <= names
+    # the trainer's runtime phases, one of each a step
+    assert {"train_step", "next_batch", "dispatch", "drain", "record"} <= names
 
     text = open(render_run(run_dir)).read()
     assert "| loss |" in text and "| ess |" in text  # percentile rows

@@ -134,6 +134,36 @@ def test_engine_records_and_occupancy():
     assert recs[4].launch >= recs[0].finish
 
 
+def test_serve_batch_spans_nest_inside_the_batch():
+    from repro.obs.trace import Tracer, tracing
+    from repro.serve.coalescer import Request
+
+    cfg, params = _sasrec()
+    eng = ServingEngine(
+        RecsysMIPSRoute(cfg, params, k=4),
+        CoalescePolicy(max_batch=4, max_wait_s=0.5),
+    )
+    eng.warmup()
+    batch = [Request(rid=r, payload=h, arrival=0.0)
+             for r, h in enumerate(_hists(cfg, 3))]
+    tr = Tracer()
+    with tracing(tr):
+        assert len(eng.serve_batch(batch)) == 3
+    ev = {e["name"]: e for e in tr.events}
+    outer = ev["serve_batch"]
+    assert outer["args"] == {"batch": 0, "n": 3}
+    phases = ["serve_prepare", "serve_run", "serve_wait", "serve_finalize",
+              "serve_record"]
+    assert set(ev) == {"serve_batch", *phases}
+    for a, b in zip(phases, phases[1:]):
+        assert ev[a]["ts"] + ev[a]["dur"] <= ev[b]["ts"]
+    for name in phases:
+        e = ev[name]
+        assert e["args"] == {"batch": 0}
+        assert outer["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= outer["ts"] + outer["dur"]
+
+
 def test_query_exact_is_exact_top_k_of_the_user_tower():
     """The planner's exact-fallback query — the reference a recall check
     holds the live index to — is exact top-k over the whole catalog,

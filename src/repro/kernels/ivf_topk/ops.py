@@ -7,7 +7,13 @@ gather streams (CT, L) tiles HBM -> VMEM instead of materialising the
 [B, n_probe*cap, L] candidate tensor. Stage 1 (centroid scoring + per-
 row top-n_probe) runs here as a plain (B, L) x (L, C) matmul — it must
 precede the kernel because the probe ids drive the scalar-prefetch
-index_maps — and stage 2 is `ivf_topk_pallas`.
+index_maps — and stage 2 is `ivf_topk_pallas`. Beside the probe ids,
+stage 1 derives each list's live tile count from the lists themselves
+(`live_tiles`), so the kernel neither fetches nor merges the cap tiles
+past a list's last occupied slot: retrieval costs what the probed lists
+hold, not their padded capacity. The lists are the only record of
+occupancy — build, refresh, `delta_append` and compaction keep them
+true — so the count needs no field of its own in `IVFIndex`.
 
 Handles n_probe clamping (<= C), padding the list capacity up to the
 cap tile (a no-op when the index was built with ``cap_tile=``), and
@@ -20,6 +26,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.backend import resolve_interpret
 from repro.kernels.ivf_topk.kernel import ivf_topk_pallas
@@ -59,12 +66,38 @@ def tile_align_index(index, cap_tile: int | None):
     return index, ct
 
 
+def live_tiles(lists, cap_tile: int):
+    """[..., capp] int32 lists -> [...] int32: the cap tiles of each list
+    up to and including the one that holds its LAST occupied slot
+    (id >= 0); 0 for an empty list. The last slot, not the count of
+    ids: `delta_append` tombstones updated items to -1 in place, and a
+    hole must not hide the live slots after it. Takes a traced jax
+    array (inside the query) or a concrete numpy one (the host gauge)."""
+    slot = np.arange(1, lists.shape[-1] + 1, dtype=np.int32)
+    end = ((lists >= 0) * slot).max(axis=-1)  # last occupied slot + 1
+    return -(-end // cap_tile)
+
+
+def live_tile_share(lists, cap_tile: int | None = None) -> float:
+    """Live cap tiles over all cap tiles of an index's lists ([..., capp],
+    any index layout), under the query's cap-tile rule: the share of a
+    uniform probe's kernel grid that does any work. Reads the lists to
+    the host — call it where an index is built or rebuilt, never per
+    step (the `ivf.live_tile_share` gauge)."""
+    lists = np.asarray(lists)
+    capp = lists.shape[-1]
+    ct = resolve_cap_tile(cap_tile, capp)
+    n_tiles = (lists.size // capp) * -(-capp // ct)
+    return float(live_tiles(lists, ct).sum()) / n_tiles
+
+
 def _probe_lists(q, probe, lists, list_embs, *, k, cap_tile, interpret):
     """One kernel pass over one padded-list table (main OR delta) with
     the already-selected probe ids; in-trace tile-align fallback for
     ad-hoc callers (no-op for cap_tile-built or tile_align_index'ed
     layouts — hot paths MUST arrive aligned, or this pad re-copies the
-    whole table inside the traced step)."""
+    whole table inside the traced step). The live tile counts come from
+    the lists as they are now: one reduction over [C, capp] ids."""
     pad = (-lists.shape[1]) % cap_tile
     if pad:
         lists = jnp.pad(lists, ((0, 0), (0, pad)), constant_values=-1)
@@ -72,6 +105,7 @@ def _probe_lists(q, probe, lists, list_embs, *, k, cap_tile, interpret):
     return ivf_topk_pallas(
         q,
         probe,
+        live_tiles(lists, cap_tile),
         lists,
         list_embs.astype(jnp.float32),
         k=k,

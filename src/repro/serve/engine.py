@@ -35,6 +35,9 @@ record-then-drain discipline as the trainer):
     serve_requests       counter
     serve_abandoned      counter, requests a failed dispatch returned
     index_health         events, when the degradation ladder is armed
+    ivf.live_tile_share  gauge, the route's index's live cap tiles over
+                         all of them: at construction and after a
+                         ladder compaction or rebuild, never per batch
 
 Engines owned by a cluster replica carry a ``labels={"replica": i}``
 tag on every record, so per-replica occupancy/queue-wait series fall
@@ -130,6 +133,7 @@ class ServingEngine:
         self.free_at = 0.0
         self.batches = 0
         self._rid = 0
+        self._gauge_live_tile_share()
 
     # -- intake ---------------------------------------------------------
     def submit(self, payload, arrival: float) -> int:
@@ -279,8 +283,18 @@ class ServingEngine:
         if action in ("compact", "rebuild"):
             with span(f"index_{action}", batch=self.batches):
                 self.route.heal(action)
+            self._gauge_live_tile_share()
         elif action == "fallback":
             self.route.degrade()
+
+    def _gauge_live_tile_share(self) -> None:
+        """The route's index as built or healed (a host float the planner
+        read from the lists then): retrieval routes only."""
+        share = getattr(self.route, "live_tile_share", None)
+        if share is not None:
+            self.bus.gauge(
+                "ivf.live_tile_share", share, step=self.batches, **self.labels
+            )
 
     # -- summaries ------------------------------------------------------
     def occupancy(self) -> float:

@@ -32,6 +32,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ivf_topk.ops import live_tile_share
+
 __all__ = ["QueryPlanner"]
 
 
@@ -88,6 +90,10 @@ class QueryPlanner:
             fcfg, retriever_kwargs={"index": index, "n_probe": self.n_probe}
         )
         self.index_state = self.plan.initial_index_state
+        # the share of the index's cap tiles that hold any item, read
+        # from the lists where they are built or rebuilt (here and in
+        # heal()); the engine emits it as the `ivf.live_tile_share` gauge
+        self.live_tile_share = live_tile_share(self.index_state.lists)
         self.fallback_plan = self.plan.degrade_to_fallback()
         self._primary = self._jit(self.plan)
         self._fallback = self._jit(self.fallback_plan)
@@ -166,6 +172,7 @@ class QueryPlanner:
     def heal(self, action: str) -> None:
         """Execute a compact/rebuild rung against the live state."""
         self.index_state = self._heal_fns[action](self.index_state, self.beta)
+        self.live_tile_share = live_tile_share(self.index_state.lists)
 
     def degrade(self) -> None:
         """The ladder's last rung: swap to the pre-resolved (and

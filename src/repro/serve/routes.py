@@ -11,7 +11,8 @@ A route is the engine's model adapter — four duck-typed members:
 
 Routes that retrieve through a `QueryPlanner` (the MIPS routes below)
 additionally expose the degradation-ladder hooks the engine's health
-monitor drives: probe / overflow / heal / degrade / degraded.
+monitor drives: probe / overflow / heal / degrade / degraded, and the
+index's `live_tile_share`, which the engine emits as a gauge.
 
 Three routes cover the arch pool:
 
@@ -102,6 +103,10 @@ class RecsysMIPSRoute:
     def heal(self, action: str) -> None:
         self.planner.heal(action)
 
+    @property
+    def live_tile_share(self) -> float:
+        return self.planner.live_tile_share
+
     def degrade(self) -> None:
         self.planner.degrade()
 
@@ -190,6 +195,10 @@ class LMGenerateRoute:
 
     def heal(self, action: str) -> None:
         self.planner.heal(action)
+
+    @property
+    def live_tile_share(self) -> float:
+        return self.planner.live_tile_share
 
     def degrade(self) -> None:
         self.planner.degrade()

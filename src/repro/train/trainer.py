@@ -37,6 +37,7 @@ from repro.core.snis import DIAGNOSTIC_KEYS
 from repro.data.loader import BatchLoader
 from repro.health.guard import grad_global_norm, init_guard_state
 from repro.data.synthetic import SessionDataset
+from repro.kernels.ivf_topk.ops import live_tile_share
 from repro.mips.exact import topk_exact
 from repro.obs.run import ObsConfig, ObsRun
 from repro.obs.schema import validate_history
@@ -143,6 +144,15 @@ class FOPOTrainer:
             self.plan.initial_index_state if self.plan is not None else None
         )
         self._maintained = self.plan is not None and self.plan.refresh is not None
+        # the share of the index's cap tiles that hold any item (the
+        # `ivf.live_tile_share` gauge), read from the lists where they
+        # are built or rebuilt on the host: here, and after a ladder
+        # compaction or rebuild; the first train() emits this reading
+        self._cap_tile = (retriever_kwargs or {}).get("cap_tile")
+        self._pending_tile_share = (
+            live_tile_share(self.index_state.lists, self._cap_tile)
+            if self.index_state is not None else None
+        )
         self._refresh_fns = self._build_refresh() if self._maintained else None
         self._refresh_key = jax.random.PRNGKey(cfg.seed + 31)
         # the training RNG is OWNED (not a train()-local): it rides the
@@ -378,6 +388,11 @@ class FOPOTrainer:
                 self.index_state = self._refresh_fns[action](
                     self.index_state, self.beta
                 )
+            bus.gauge(
+                "ivf.live_tile_share",
+                live_tile_share(self.index_state.lists, self._cap_tile),
+                step=self.step,
+            )
         elif action == "fallback":
             self._degrade()
 
@@ -526,6 +541,11 @@ class FOPOTrainer:
         with ObsRun(cfg.obs, predicted_step_s=self._predicted_step_s()) as run:
             if self._monitor is not None:
                 self._monitor.bind_bus(run.bus)
+            if self._pending_tile_share is not None:
+                run.bus.gauge(
+                    "ivf.live_tile_share", self._pending_tile_share, step=self.step
+                )
+                self._pending_tile_share = None
             for _ in range(n):
                 with span("train_step", step=self.step):
                     self._step(run, log_every)

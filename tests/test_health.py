@@ -685,6 +685,37 @@ def test_full_ladder_walk_to_exact_fallback(ds):
     assert len(hist["loss"]) == 6
 
 
+def test_live_tile_share_gauge_at_build_and_ladder_heals(ds, monkeypatch):
+    """`ivf.live_tile_share` is read from the lists where they are built
+    or rebuilt on the host: the trainer's construction (emitted by the
+    first train()), then each ladder compaction and rebuild — never per
+    step, and not for the scheduled centroid refresh."""
+    from repro.kernels.ivf_topk import live_tile_share
+    from repro.obs.bus import MetricsBus
+
+    seen = []
+    gauge = MetricsBus.gauge
+
+    def spy(self, name, value, **kw):
+        if name == "ivf.live_tile_share":
+            seen.append((kw.get("step"), value))
+        gauge(self, name, value, **kw)
+
+    monkeypatch.setattr(MetricsBus, "gauge", spy)
+    ih = IndexHealthConfig(probe_every=1, probe_rows=32, probe_k=16,
+                           recall_floor=1.01, cooldown=0)
+    t = make_refresh_trainer(ds, health=HealthConfig(index=ih), steps=6)
+    built = live_tile_share(t.index_state.lists, 32)
+    assert 0 < built < 1  # a lopsided catalog: most lists end early
+    actions = [e["action"] for e in t.train(3)["index_health"]]
+    t.train(3)
+    assert actions == list(LADDER)
+    # construction, then the compact (step 1) and the rebuild (step 2)
+    assert [step for step, _ in seen] == [0, 1, 2]
+    assert seen[0][1] == built
+    assert all(0 < v <= 1 for _, v in seen)
+
+
 def test_degrade_requires_fallback_retriever():
     from repro.core.plan import ExecutionPlan
 

@@ -14,37 +14,135 @@ from repro.core import ExecutionPlan, FOPOConfig, fopo_loss
 from repro.core.policy import SoftmaxPolicy, linear_tower_apply, linear_tower_init
 from repro.core.rewards import make_session_reward
 from repro.data import clustered_catalog
-from repro.kernels.ivf_topk import ivf_topk, ivf_topk_ref
+from repro.kernels.ivf_topk import ivf_topk, ivf_topk_ref, live_tile_share, live_tiles
 from repro.mips import build_ivf, build_ivf_sharded, ivf_query, recall_at_k, topk_exact
+from repro.mips.ivf import IVFIndex
 
 
 # ---------------------------------------------------------------------------
 # kernel vs jnp ref — one candidate set, element-for-element
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize(
-    "p,l,c,b,k,n_probe,cap_tile",
-    [
-        (500, 16, 8, 4, 16, 3, 8),     # ragged clusters, CT | cap
-        (777, 8, 16, 5, 32, 8, 16),    # odd P
-        (256, 32, 4, 3, 8, 2, 128),    # CT > cap -> clamped to cap
-        (300, 16, 8, 4, 16, 5, 7),     # CT does not divide cap -> pad path
-        (64, 8, 64, 2, 8, 64, 8),      # one item per cluster (C == P region)
-    ],
-)
-def test_ivf_topk_matches_ref(p, l, c, b, k, n_probe, cap_tile):
+def _case(*args, layout="built"):
+    """A parity case (p, l, c, b, k, n_probe, cap_tile) over an index of
+    the given layout; the built-index cases keep their plain ids."""
+    name = "-".join(map(str, args))
+    return pytest.param(
+        *args, layout, id=name if layout == "built" else f"{layout}-{name}"
+    )
+
+
+def _index_of_lengths(items, lengths, cap, key):
+    """An IVFIndex whose list c holds lengths[c] distinct items at its
+    head (the rest -1 / 0 padding) under random centroids."""
+    lists = np.full((len(lengths), cap), -1, np.int32)
+    start = np.cumsum([0, *lengths[:-1]])
+    for c, (s0, n) in enumerate(zip(start, lengths)):
+        lists[c, :n] = np.arange(s0, s0 + n)
+    embs = np.where(
+        (lists >= 0)[..., None], np.asarray(items)[np.maximum(lists, 0)], 0.0
+    )
+    centroids = jax.random.normal(key, (len(lengths), items.shape[1]))
+    return IVFIndex(centroids, jnp.asarray(lists),
+                    jnp.asarray(embs, jnp.float32), items.shape[0])
+
+
+def _parity_inputs(layout, p, l, c, b, k, n_probe, cap_tile):
+    """(index, queries, delta, reference TopK) of one parity case.
+
+    built        the k-means index as `build_ivf` lays it out
+    mixed        lists of 0 (two), 1, 3, CT + 1, 2 CT, cap - 1 items and
+                 one full to the cap
+    empty_probe  as mixed, with row 0's probed lists all empty: it
+                 back-fills NEG_INF / -1 from the carry's initialisation
+    holes        `delta_append` tombstoned the first two tiles of the
+                 longest list: its live slots lie after the hole
+    delta        a few appends in the (mostly empty) delta buffers,
+                 probed beside the main lists"""
+    from repro.mips.refresh import delta_append, init_refresh_state, refresh_query
+
     kq, ki = jax.random.split(jax.random.PRNGKey(p + k))
     items = jax.random.normal(ki, (p, l))
     q = jax.random.normal(kq, (b, l))
+    if layout in ("mixed", "empty_probe"):
+        ct, cap = cap_tile, 6 * cap_tile
+        lengths = [0, cap, 1, ct + 1, 0, 2 * ct, cap - 1, 3]
+        assert len(lengths) == c and sum(lengths) <= p
+        index = _index_of_lengths(items, lengths, cap, jax.random.PRNGKey(3))
+        if layout == "empty_probe":
+            # row 0 scores the two empty lists (0 and 4) above the rest
+            cent = index.centroids.at[0].set(100 * q[0]).at[4].set(99 * q[0])
+            index = index._replace(centroids=cent)
+        return index, q, None, ivf_topk_ref(q, index, k, n_probe=n_probe)
     index = build_ivf(jax.random.PRNGKey(3), items, num_clusters=c, kmeans_iters=6)
-    ref = ivf_topk_ref(q, index, k, n_probe=n_probe)
-    out = ivf_topk(q, index, k, n_probe=n_probe, cap_tile=cap_tile, interpret=True)
+    if layout == "built":
+        return index, q, None, ivf_topk_ref(q, index, k, n_probe=n_probe)
+    lists = np.asarray(index.lists)
+    if layout == "holes":
+        longest = int(np.argmax((lists >= 0).sum(1)))
+        moved = lists[longest, : 2 * cap_tile]
+        assert (moved >= 0).all() and (lists[longest, 2 * cap_tile:] >= 0).any()
+        state = init_refresh_state(index, p, delta_cap=2 * cap_tile)
+    else:  # delta: re-embed three items of the first probed rows' lists
+        moved = lists[lists >= 0][:3]
+        state = init_refresh_state(index, p, delta_cap=cap_tile)
+    state = delta_append(state, jnp.asarray(moved), items[moved] * 1.5)
+    if layout == "holes":
+        assert (np.asarray(state.lists)[longest, : 2 * cap_tile] == -1).all()
+        index = state.as_index(p)
+        return index, q, None, ivf_topk_ref(q, index, k, n_probe=n_probe)
+    return (state.as_index(p), q, state.delta(),
+            refresh_query(state, q, k, n_probe))
+
+
+@pytest.mark.parametrize(
+    "p,l,c,b,k,n_probe,cap_tile,layout",
+    [
+        _case(500, 16, 8, 4, 16, 3, 8),     # ragged clusters, CT | cap
+        _case(777, 8, 16, 5, 32, 8, 16),    # odd P
+        _case(256, 32, 4, 3, 8, 2, 128),    # CT > cap -> clamped to cap
+        _case(300, 16, 8, 4, 16, 5, 7),     # CT does not divide cap -> pad path
+        _case(64, 8, 64, 2, 8, 64, 8),      # one item per cluster (C == P region)
+        _case(200, 16, 8, 4, 128, 8, 8, layout="mixed"),
+        _case(200, 16, 8, 3, 16, 2, 8, layout="empty_probe"),
+        _case(600, 16, 8, 4, 128, 8, 8, layout="holes"),
+        _case(500, 16, 8, 4, 32, 3, 8, layout="delta"),
+    ],
+)
+def test_ivf_topk_matches_ref(p, l, c, b, k, n_probe, cap_tile, layout):
+    """The kernel returns the reference's ids exactly, in order, and its
+    scores to float32 rounding — whatever share of each probed list's
+    cap tiles is live (the dead ones are skipped)."""
+    index, q, delta, ref = _parity_inputs(layout, p, l, c, b, k, n_probe, cap_tile)
+    out = ivf_topk(q, index, k, n_probe=n_probe, cap_tile=cap_tile,
+                   interpret=True, delta=delta)
     np.testing.assert_allclose(
         np.asarray(out.scores), np.asarray(ref.scores), rtol=1e-5, atol=1e-6
     )
-    assert (
-        np.sort(np.asarray(out.indices), -1) == np.sort(np.asarray(ref.indices), -1)
-    ).all()
+    np.testing.assert_array_equal(np.asarray(out.indices), np.asarray(ref.indices))
+    if layout == "empty_probe":
+        assert (np.asarray(out.indices)[0] == -1).all()
+        assert (np.asarray(out.scores)[0] < -1e37).all()
+
+
+def test_live_tiles_end_at_the_last_occupied_slot():
+    """A list's live tiles run to the tile of its LAST id >= 0: a hole
+    does not end the list, an empty list has none; the gauge's share
+    counts them over all tiles, under the query's cap-tile rule."""
+    lists = np.array([
+        [-1, -1, -1, -1, -1, -1, -1, -1],  # empty
+        [0, 1, 2, -1, -1, -1, -1, -1],  # head only
+        [-1, -1, -1, -1, -1, 5, -1, -1],  # live slot after a hole
+        [3, -1, -1, -1, -1, -1, -1, 4],  # holes, last slot live
+        [6, 7, 8, 9, 10, 11, 12, 13],  # full to the cap
+    ], np.int32)
+    want = [0, 2, 3, 4, 4]
+    np.testing.assert_array_equal(live_tiles(lists, 2), want)
+    traced = jax.jit(lambda x: live_tiles(x, 2))(jnp.asarray(lists))
+    np.testing.assert_array_equal(np.asarray(traced), want)
+    assert live_tile_share(lists, 2) == sum(want) / 20
+    assert live_tile_share(jnp.asarray(lists), 3) == 9 / 15  # CT 3 pads to 9
+    assert live_tile_share(lists[None]) == 4 / 5  # CT clamps to the cap
 
 
 def test_ivf_topk_exhaustive_probe_equals_exact():
@@ -283,6 +381,8 @@ kq, ki = jax.random.split(jax.random.PRNGKey(0))
 q = jax.random.normal(kq, (8, 16))
 items = jax.random.normal(ki, (777, 16))  # ragged: 777 over 4... 2 shards
 shards = build_ivf_sharded(jax.random.PRNGKey(2), items, 2, num_clusters=16, cap_tile=16)
+from repro.kernels.ivf_topk import live_tile_share
+assert live_tile_share(shards.lists, 16) < 0.9  # each shard skips dead tiles
 out = dist_ivf_topk(q, shards, 32, dist, n_probe=16, cap_tile=16, interpret=True)
 ref = topk_exact(q, items, 32)
 np.testing.assert_allclose(np.asarray(out.scores), np.asarray(ref.scores), rtol=1e-5)

@@ -289,6 +289,39 @@ def test_fault_drill_walks_ladder_to_fallback():
     assert all(np.all(np.asarray(r.result[0]) >= 0) for r in post)
 
 
+def test_live_tile_share_gauge_at_build_and_heal_never_per_batch():
+    """The engine emits its route's `ivf.live_tile_share` at construction
+    and after each ladder compaction or rebuild — the planner read it
+    from the lists then — and not once per batch."""
+    from repro.health.index_health import IndexHealthConfig
+    from repro.kernels.ivf_topk import live_tile_share
+    from repro.obs.bus import MetricsBus
+    from repro.obs.sinks import RingSink
+
+    cfg, params = _sasrec()
+    ring = RingSink()
+    eng = ServingEngine(
+        RecsysMIPSRoute(cfg, params, k=4, probe_hists=np.stack(_hists(cfg, 8))),
+        CoalescePolicy(max_batch=2, max_wait_s=0.5),
+        bus=MetricsBus([ring]),
+        health=IndexHealthConfig(
+            probe_every=2, probe_k=8, recall_floor=1.01, cooldown=0
+        ),
+    )
+    planner = eng.route.planner
+    built = planner.live_tile_share
+    assert built == live_tile_share(planner.index_state.lists)
+    eng.warmup()
+    _run_all(eng, _hists(cfg, 16), [0.0] * 16)
+    assert eng.batches == 8
+    gauges = [r for r in ring.records if r["name"] == "ivf.live_tile_share"]
+    # construction (batch 0), the compact (batch 2), the rebuild (batch 4);
+    # the fallback (batch 6) leaves the index
+    assert [r["step"] for r in gauges] == [0, 2, 4]
+    assert gauges[0]["value"] == built
+    assert all(0 < r["value"] <= 1 for r in gauges)
+
+
 # ---------------------------------------------------------------------------
 # obs: the serving section of the run report
 # ---------------------------------------------------------------------------
